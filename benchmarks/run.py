@@ -29,7 +29,9 @@ import traceback
 
 def main() -> None:
   from repro.analysis.sanitize import maybe_enable_sanitize
+  from repro.compile_cache import enable_compile_cache
   maybe_enable_sanitize()  # REPRO_SANITIZE=1: debug_nans + analyzer preflight
+  enable_compile_cache()
   from benchmarks import (algo_opts, apps_bench, area_table, arena_bench,
                           dispatch_bench, microbench_shapes,
                           microbench_square, qos_bench, resilience_bench,

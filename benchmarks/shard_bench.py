@@ -145,8 +145,9 @@ def main(argv=None):
     return subprocess.call([sys.executable, os.path.abspath(__file__),
                             *(argv or sys.argv[1:])], env=env)
 
+  from repro.core.distributed import make_mesh
   dims = tuple(int(x) for x in args.mesh.split(","))
-  mesh = jax.make_mesh(dims, ("data", "model"))
+  mesh = make_mesh(dims)
   buckets = tuple(int(b) for b in args.buckets.split(","))
 
   rows = []

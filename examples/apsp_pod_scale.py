@@ -15,12 +15,12 @@ def main():
   from repro.apps import graphs
   from repro.apps.baselines import apsp_np
   from repro.core import prepare_adjacency
-  from repro.core.distributed import distributed_leyzorek
+  from repro.core.distributed import distributed_leyzorek, make_mesh
 
   n_dev = len(jax.devices())
   model = 4 if n_dev % 4 == 0 and n_dev >= 4 else 1
   data = max(1, n_dev // model)
-  mesh = jax.make_mesh((data, model), ("data", "model"))
+  mesh = make_mesh((data, model))
   print(f"mesh: data={data} × model={model} ({n_dev} devices)")
 
   n = 512

@@ -18,13 +18,14 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 
 from repro.apps import graphs, solvers  # noqa: E402
+from repro.core.distributed import make_mesh  # noqa: E402
 from repro.serve_mmo import MMOEngine, apsp_request  # noqa: E402
 
 
 def main():
   n_dev = len(jax.devices())
   dims = (2, 4) if n_dev >= 8 else (1, n_dev)
-  mesh = jax.make_mesh(dims, ("data", "model"))
+  mesh = make_mesh(dims)
   print(f"mesh: data={dims[0]} × model={dims[1]} on {n_dev} "
         f"{jax.default_backend()} devices")
 

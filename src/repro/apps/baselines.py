@@ -4,6 +4,8 @@ SIMD²-ized solver is validated against a *different* algorithm, exactly as
 the paper's correctness-validation flow demands (§5.1.2):
 
   APSP/APLP/MaxCP/MaxRP/MinRP → Floyd-Warshall k-pivot recurrences
+  APSP rows                   → single-source dense Dijkstra
+  raw mmo                     → blocked float64 ⊕/⊗ loop over K
   MST                         → Kruskal with union-find (+ tree path maxima)
   GTC                         → per-source BFS reachability
   KNN                         → brute-force norm expansion + argpartition
@@ -26,6 +28,39 @@ def floyd_warshall_np(adj: np.ndarray, oplus, otimes) -> np.ndarray:
 
 def apsp_np(w: np.ndarray) -> np.ndarray:
   return floyd_warshall_np(w, np.minimum, np.add)
+
+
+def sssp_np(w: np.ndarray, src: int) -> np.ndarray:
+  """Single-source shortest paths by dense Dijkstra (weights >= 0, +inf
+  where no edge): one row of ``apsp_np`` in O(n²) — the sampled-row
+  reference when the O(n³) all-pairs recurrence is too slow."""
+  w = np.asarray(w, np.float64)
+  n = w.shape[0]
+  dist = np.full(n, np.inf)
+  dist[src] = 0.0
+  done = np.zeros(n, bool)
+  for _ in range(n):
+    u = int(np.argmin(np.where(done, np.inf, dist)))
+    if done[u] or not np.isfinite(dist[u]):
+      break
+    done[u] = True
+    dist = np.minimum(dist, dist[u] + w[u])
+  return dist
+
+
+def mmo_np(a: np.ndarray, b: np.ndarray, oplus, otimes,
+           block: int = 64) -> np.ndarray:
+  """⊕_k ⊗(a[i,k], b[k,j]) by a blocked loop over K in float64 — the plain
+  reference for one raw mmo instruction."""
+  a = np.asarray(a, np.float64)
+  b = np.asarray(b, np.float64)
+  out = None
+  for k0 in range(0, a.shape[1], block):
+    with np.errstate(invalid="ignore", over="ignore"):
+      prod = otimes(a[:, k0:k0 + block, None], b[None, k0:k0 + block, :])
+    part = oplus.reduce(prod, axis=1)
+    out = part if out is None else oplus(out, part)
+  return out
 
 
 def aplp_np(w: np.ndarray) -> np.ndarray:

@@ -42,34 +42,27 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core.mmo import mmo as _mmo
 from repro.core import semiring as sr_mod
 
-if hasattr(jax, "shard_map"):
-  shard_map = jax.shard_map
-else:  # pragma: no cover — older jax keeps it under experimental
-  from jax.experimental.shard_map import shard_map
-
-# jax.lax.pvary only exists on newer jax (varying-axis annotations for
-# shard_map rep-checking); older versions don't need the annotation.
-pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
-
-def _shard_map(kernel, *, mesh, in_specs, out_specs, check_rep=True):
-  """shard_map with a version-tolerant ``check_rep``: the ragged masked-K
-  path lowers its dynamic K-block trip count to a ``while``, which has no
-  replication rule — those callers pass check_rep=False.  Newer jax versions
-  renamed/dropped the kwarg, so fall back to the bare call."""
-  try:
-    return shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_rep)
-  except TypeError:  # pragma: no cover — future jax without check_rep
-    return shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs)
-
 Array = jax.Array
+
+
+def make_mesh(shape, axes=("data", "model"), *, devices=None) -> Mesh:
+  """A device mesh whose axes are all Auto: jit places the schedules'
+  shard_maps on it without entering a ``jax.set_mesh`` context (the
+  default Explicit axes would demand one for every call)."""
+  return jax.make_mesh(tuple(shape), tuple(axes),
+                       axis_types=(AxisType.Auto,) * len(axes),
+                       devices=devices)
+
+
+def _varying(x, axis):
+  """Mark a replicated loop carry as varying over ``axis`` so shard_map's
+  type check accepts the per-device values the loop writes into it."""
+  return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def _local_contract(a, b, sr_name, backend):
@@ -94,8 +87,8 @@ def mmo_kspan(a: Array, b: Array, c: Optional[Array], *, op: str, mesh: Mesh,
 
   in_specs = (P(None, axis), P(axis, None),
               None if c is None else P(None, None))
-  fn = shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                 out_specs=P(None, None))
+  fn = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                     out_specs=P(None, None))
   return fn(a, b, c)
 
 
@@ -118,9 +111,9 @@ def summa_mmo(a: Array, b: Array, c: Optional[Array], *, op: str, mesh: Mesh,
     return out
 
   spec = P(row_axis, col_axis)
-  fn = shard_map(kernel, mesh=mesh,
-                 in_specs=(spec, spec, None if c is None else spec),
-                 out_specs=spec)
+  fn = jax.shard_map(kernel, mesh=mesh,
+                     in_specs=(spec, spec, None if c is None else spec),
+                     out_specs=spec)
   return fn(a, b, c)
 
 
@@ -155,7 +148,7 @@ def ring_mmo(a: Array, b: Array, c: Optional[Array], *, op: str, mesh: Mesh,
 
     m = a_blk.shape[0]
     acc0 = sr.identity_like((m, n_cols), sr.acc_dtype(a_blk.dtype))
-    acc0 = pvary(acc0, (axis,))
+    acc0 = _varying(acc0, axis)
     _, acc = jax.lax.fori_loop(0, n_dev, step, (b_blk, acc0))
     if c_blk is not None:
       acc = sr.oplus(acc, c_blk.astype(acc.dtype))
@@ -163,8 +156,8 @@ def ring_mmo(a: Array, b: Array, c: Optional[Array], *, op: str, mesh: Mesh,
 
   in_specs = (P(None, None), P(axis, None),
               None if c is None else P(None, axis))
-  fn = shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                 out_specs=P(None, axis))
+  fn = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                     out_specs=P(None, axis))
   return fn(a, b, c)
 
 
@@ -219,8 +212,10 @@ def mmo_dp_batched(a: Array, b: Array, c: Optional[Array] = None, *,
 
   in_specs = (spec, spec, None if c is None else spec,
               None if k_valid is None else P(axes))
-  fn = _shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=spec,
-                  check_rep=k_valid is None)
+  # the ragged masked-K path lowers its dynamic K-block trip count to a
+  # `while`, which has no varying-axis rule: those calls skip the check
+  fn = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                     check_vma=k_valid is None)
   return fn(a, b, c, k_valid)
 
 
@@ -248,9 +243,9 @@ def mmo_kspan_batched(a: Array, b: Array, c: Optional[Array] = None, *,
   in_specs = (P(None, None, axis), P(None, axis, None),
               None if c is None else P(None, None, None),
               None if k_valid is None else P(None))
-  fn = _shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                  out_specs=P(None, None, None),
-                  check_rep=k_valid is None)
+  fn = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                     out_specs=P(None, None, None),
+                     check_vma=k_valid is None)
   return fn(a, b, c, k_valid)
 
 
@@ -278,10 +273,10 @@ def summa_mmo_batched(a: Array, b: Array, c: Optional[Array] = None, *,
     return out
 
   spec = P(None, row_axis, col_axis)
-  fn = _shard_map(kernel, mesh=mesh,
-                  in_specs=(spec, spec, None if c is None else spec,
-                            None if k_valid is None else P(None)),
-                  out_specs=spec, check_rep=k_valid is None)
+  fn = jax.shard_map(kernel, mesh=mesh,
+                     in_specs=(spec, spec, None if c is None else spec,
+                               None if k_valid is None else P(None)),
+                     out_specs=spec, check_vma=k_valid is None)
   return fn(a, b, c, k_valid)
 
 
@@ -317,7 +312,7 @@ def ring_mmo_batched(a: Array, b: Array, c: Optional[Array] = None, *,
 
     r, m = a_blk.shape[0], a_blk.shape[1]
     acc0 = sr.identity_like((r, m, n_cols), sr.acc_dtype(a_blk.dtype))
-    acc0 = pvary(acc0, (axis,))
+    acc0 = _varying(acc0, axis)
     _, acc = jax.lax.fori_loop(0, n_dev, step, (b_blk, acc0))
     if c_blk is not None:
       acc = sr.oplus(acc, c_blk.astype(acc.dtype))
@@ -326,9 +321,9 @@ def ring_mmo_batched(a: Array, b: Array, c: Optional[Array] = None, *,
   in_specs = (P(None, None, None), P(None, axis, None),
               None if c is None else P(None, None, axis),
               None if k_valid is None else P(None))
-  fn = _shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                  out_specs=P(None, None, axis),
-                  check_rep=k_valid is None)
+  fn = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                     out_specs=P(None, None, axis),
+                     check_vma=k_valid is None)
   return fn(a, b, c, k_valid)
 
 
@@ -470,11 +465,11 @@ def _dp_closure_fn(op: str, algorithm: str, backend: str,
     return solver(adj_blk, op=op, backend=backend, mmo_fn=_local_mmo_fn(
         block, interpret), max_iters=max_iters, valid_n=vn_blk)
 
-  return jax.jit(_shard_map(
+  return jax.jit(jax.shard_map(
       kernel, mesh=mesh,
       in_specs=(P(axes, None, None), P(axes) if has_valid else None),
       out_specs=(P(axes, None, None), P(axes)),
-      check_rep=False))  # per-shard fixpoint lowers to `while`
+      check_vma=False))  # per-shard fixpoint lowers to `while`
 
 
 # ---------------------------------------------------------------------------

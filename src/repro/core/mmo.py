@@ -42,6 +42,10 @@ from repro.core import semiring as sr_mod
 Array = jax.Array
 
 _DEFAULT_BLOCK_K = 512
+# Every f32 MXU contraction (the XLA rewrites here, the Pallas kernels in
+# kernels/) runs at this one precision, so the backends agree on the chip as
+# they do on CPU: TPU's default f32 matmul precision is a single bf16 pass.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
 # Aim for at least this many dynamic K-blocks when a k_valid hint is present,
 # so skipping dead blocks has useful granularity.
 _DYN_K_BLOCKS = 8
@@ -150,13 +154,15 @@ def _contract_vector_dynk(a: Array, b: Array, sr: sr_mod.Semiring,
 
 def _contract_matmul(a: Array, b: Array, sr: sr_mod.Semiring) -> Array:
   del sr
-  return jnp.matmul(a, b, preferred_element_type=jnp.float32)
+  return jnp.matmul(a, b, preferred_element_type=jnp.float32,
+                    precision=MXU_PRECISION)
 
 
 def _contract_addnorm(a: Array, b: Array, sr: sr_mod.Semiring) -> Array:
   """Σ_k (a−b)² = Σa² − 2Σab + Σb² — the O(K·M·N) term rides the MXU."""
   del sr
-  ab = jnp.matmul(a, b, preferred_element_type=jnp.float32)
+  ab = jnp.matmul(a, b, preferred_element_type=jnp.float32,
+                  precision=MXU_PRECISION)
   a2 = jnp.sum(jnp.square(a.astype(jnp.float32)), axis=-1, keepdims=True)
   b2 = jnp.sum(jnp.square(b.astype(jnp.float32)), axis=-2, keepdims=True)
   return a2 - 2.0 * ab + b2
@@ -169,6 +175,8 @@ def _contract_orand(a: Array, b: Array, sr: sr_mod.Semiring) -> Array:
       jnp.bfloat16)
   bf = b.astype(jnp.bfloat16) if b.dtype == jnp.bool_ else (b != 0).astype(
       jnp.bfloat16)
+  # {0,1} operands are exact in bf16 and the f32 count is exact, so this
+  # contraction needs no precision pin
   cnt = jnp.matmul(af, bf, preferred_element_type=jnp.float32)
   return cnt > 0.5
 

@@ -7,8 +7,8 @@ re-reads it for the next contraction.  The TCU computational model
 iterative matrix algorithms, so this kernel keeps each request's iterate
 resident in VMEM and runs **G whole iterations per grid visit**:
 
-  * grid = (requests, G, output row-blocks); the request dim is parallel
-    (Megacore splits it), the iteration and block dims are sequential.
+  * grid = (requests, G, output row-blocks), all sequential (the flag
+    outputs are whole-array SMEM buffers every step shares).
   * the output ref doubles as the on-chip iterate: initialized from the
     incoming stack at (g == 0, i == 0), updated in place each iteration, and
     flushed to HBM once per request — HBM traffic is paid once per G
@@ -20,15 +20,15 @@ resident in VMEM and runs **G whole iterations per grid visit**:
     without any host observation.
   * the per-request convergence reduction — ``_changed``'s inf-aware (and
     NaN-aware) compare — runs in-kernel on the last block of each iteration
-    and lands in an output flag vector the host driver folds back into the
-    surrounding ``lax.while_loop``.
+    and lands in an SMEM output flag vector the host driver folds back into
+    the surrounding ``lax.while_loop``.
   * a ``pl.CostEstimate`` tells XLA the launch covers R·G contractions'
     worth of flops over one chunk's worth of HBM bytes, so it schedules the
     fused program sanely instead of assuming one-matmul cost.
 
 Why G-iteration chunks instead of unrolling the whole fixpoint on-chip: the
 iterate must stay fully VMEM-resident (each iteration reads every row of the
-previous one), which caps n, and worst-case trip counts (n−1 for
+previous one), which caps n (``MAX_N``), and worst-case trip counts (n−1 for
 Bellman-Ford) would force a worst-case-sized launch even though most batches
 converge early.  Chunking keeps the early-exit: the host ``while_loop`` asks
 for at most G more iterations, re-checks ``any(active)``, and stops — frozen
@@ -50,113 +50,128 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import closure as cl_mod
 from repro.core import semiring as sr_mod
-from repro.kernels.semiring_mmo import (_CompilerParams, _float_ring, _rup,
-                                        _SUBLANES)
+from repro.core.mmo import MXU_PRECISION
+from repro.kernels.semiring_mmo import _LANES, _float_ring, _rup, rank1_fold
 
 Array = jax.Array
 
 DEFAULT_G = 8  # chunk length: fixpoint iterations fused per kernel launch
 
+# Largest padded n the megakernel serves.  Each request's whole iterate is
+# VMEM-resident: the double-buffered input and output blocks, the scratch
+# iterate, and for Bellman-Ford the double-buffered adjacency — 7 × n² × 4 B,
+# 28 MiB at n = 1024 and 112 MiB at n = 2048 against v5e's 128 MiB of VMEM.
+# tests/test_tpu_compile.py compiles every pow2 bucket up to this cap for a
+# described v5e; the engine routes larger closure buckets to the
+# per-iteration path instead of letting a compile fail.
+MAX_N = 1024
 
-def _slab_contract(sr: sr_mod.Semiring, a_slab: Array, b_full: Array,
-                   kv, acc_dtype) -> Array:
-  """One (bm, K) × (K, N) row-slab contraction against the full resident
-  iterate.  K is never split across grid steps (the whole matrix is already
-  in VMEM), so mma keeps the reference's single-dot summation order — the
-  bit-parity contract with the per-iteration path.
+# Mosaic's own scratch on top of the blocks: the v5e compile needs ~12 MiB
+# beyond them at every n from 256 to 1024
+_VMEM_HEADROOM = 16 << 20
 
-  ``kv`` (traced int32) bounds the VPU rank-u sliver loop: lanes at or
-  beyond a request's live-n are isolated-vertex padding whose ⊗ terms are
-  ⊕-identity no-ops, so min/max rings skip them (exact algebra — dropping
-  exact no-ops cannot move a min/max).  The MXU path ignores the hint, like
-  the per-contraction kernel: full padded K on the MXU is already cheap.
-  """
-  if sr.name == "mma":
-    return jnp.dot(a_slab, b_full, preferred_element_type=jnp.float32)
 
-  oplus, otimes = _float_ring(sr)
-  bm, kp = a_slab.shape
-  bn = b_full.shape[1]
-  u = min(_SUBLANES, kp)
-
-  def sliver(j):
-    a_s = jax.lax.dynamic_slice(a_slab, (0, j * u), (bm, u)).astype(acc_dtype)
-    b_s = jax.lax.dynamic_slice(b_full, (j * u, 0), (u, bn)).astype(acc_dtype)
-    prod = otimes(a_s[:, :, None], b_s[None, :, :])  # (bm, u, bn)
-    part = prod[:, 0, :]
-    for t in range(1, u):  # u is tiny & static: unrolled ⊕-tree
-      part = oplus(part, prod[:, t, :])
-    return part
-
-  # sliver 0 always runs: every live request has kv >= 1
-  acc = sliver(0)
-  nlive = (kv + u - 1) // u  # live slivers — the ragged masked-K trip count
-
-  def body(j, acc):
-    return oplus(acc, sliver(j))
-
-  return jax.lax.fori_loop(1, nlive, body, acc)
+def vmem_limit_bytes(np_: int, itemsize: int, has_adj: bool) -> int:
+  """Scoped-VMEM budget for one launch at padded size ``np_``."""
+  mats = 2 + 2 + 1 + (2 if has_adj else 0)
+  return mats * np_ * np_ * itemsize + _VMEM_HEADROOM
 
 
 def _make_fixpoint_kernel(sr: sr_mod.Semiring, acc_dtype, nblk: int, bm: int,
                           has_adj: bool):
   """Kernel factory; ``has_adj`` selects Bellman-Ford (D ← D ⊕ (D ⊗ A),
-  constant second operand) vs repeated squaring (C ← C ⊕ (C ⊗ C))."""
-  oplus, _ = _float_ring(sr)
+  constant second operand) vs repeated squaring (C ← C ⊕ (C ⊗ C)).
+
+  Each grid step computes one (bm, np_) row slab of the next iterate into
+  scratch, one (bm, bn) column block at a time.  The min/max rings read K
+  through the refs in lane-aligned chunks, bounded by the request's live n
+  (isolated-vertex padding lanes are ⊕-identity no-ops — exact algebra);
+  mma contracts each column block on the MXU over the full padded K, so its
+  per-element summation order matches the per-iteration path.
+  """
+  oplus, otimes = _float_ring(sr)
   boolean = sr.boolean
+  np_ = nblk * bm
+  bn = ck = min(_LANES, np_)
+  ncol, nchunk = np_ // bn, np_ // ck
+
+  def rows(s):
+    return pl.ds(pl.multiple_of(s * bm, bm), bm)
 
   def fixpoint_kernel(kv_ref, act0_ref, it0_ref, glim_ref, *refs):
     # scalar-prefetch refs first (SMEM, whole vectors, indexable by request)
     if has_adj:
-      c_ref, adj_ref = refs[0], refs[1]
-      o_ref, it_ref, act_ref, new_ref = refs[2], refs[3], refs[4], refs[5]
+      c_ref, b_ref, o_ref, it_ref, act_ref, new_ref = refs
     else:
-      c_ref, adj_ref = refs[0], None
-      o_ref, it_ref, act_ref, new_ref = refs[1], refs[2], refs[3], refs[4]
-
+      c_ref, o_ref, it_ref, act_ref, new_ref = refs
+      b_ref = o_ref
     r = pl.program_id(0)
     g = pl.program_id(1)
     i = pl.program_id(2)
 
     @pl.when((g == 0) & (i == 0))
     def _init():
-      # seed the VMEM-resident iterate + per-request flags for this request
-      o_ref[0] = c_ref[0].astype(acc_dtype)
-      it_ref[0, 0] = it0_ref[r]
-      act_ref[0, 0] = act0_ref[r]
+      # seed the VMEM-resident iterate + this request's SMEM flags
+      def seed(s, carry):
+        o_ref[0, rows(s), :] = c_ref[0, rows(s), :].astype(acc_dtype)
+        return carry
+      jax.lax.fori_loop(0, nblk, seed, 0)
+      it_ref[r] = it0_ref[r]
+      act_ref[r] = act0_ref[r]
 
     # frozen requests (and steps past the request's live budget) skip every
     # contraction — one scalar test per grid step, no host round-trip.  The
     # budget is a per-request vector: the batched driver broadcasts one
     # chunk-wide value, the arena hands every slot its own remaining cap so
     # slots admitted at different times share a launch without over-running.
-    live = (act_ref[0, 0] != 0) & (g < glim_ref[r])
+    live = (act_ref[r] != 0) & (g < glim_ref[r])
 
     @pl.when(live)
     def _compute():
-      old_slab = o_ref[0, pl.ds(i * bm, bm), :]
-      b_full = adj_ref[0] if has_adj else o_ref[0]
-      part = _slab_contract(sr, old_slab, b_full, kv_ref[r], acc_dtype)
-      new_ref[pl.ds(i * bm, bm), :] = oplus(part, old_slab)
+      a_rows = rows(i)
+      # live K chunks: the ragged masked-K trip count (chunk 0 always runs —
+      # every live request has kv >= 1)
+      nlive = jnp.clip((kv_ref[r] + ck - 1) // ck, 1, nchunk)
+
+      def column(j, carry):
+        cols = pl.ds(pl.multiple_of(j * bn, bn), bn)
+        if sr.name == "mma":
+          part = jnp.dot(o_ref[0, a_rows, :], b_ref[0, :, cols],
+                         preferred_element_type=jnp.float32,
+                         precision=MXU_PRECISION)
+        else:
+          def fold(c, acc):
+            ks = pl.ds(pl.multiple_of(c * ck, ck), ck)
+            return rank1_fold(oplus, otimes, acc,
+                              o_ref[0, a_rows, ks].astype(acc_dtype),
+                              b_ref[0, ks, cols].astype(acc_dtype))
+          part = jax.lax.fori_loop(1, nlive, fold, fold(0, None))
+        new_ref[a_rows, cols] = oplus(part, o_ref[0, a_rows, cols])
+        return carry
+
+      jax.lax.fori_loop(0, ncol, column, 0)
 
     @pl.when(live & (i == nblk - 1))
     def _commit():
-      # all row slabs of this iteration are in scratch; run the convergence
+      # every row slab of this iteration is in scratch: run the convergence
       # reduction (inf- and NaN-aware, matching core.closure._changed) and
-      # advance the iterate + flags in place
-      old = o_ref[0]
-      new = new_ref[...]
-      if boolean:
-        same = new == old  # float {0,1} domain — plain equality is exact
-      else:
-        same = ((new == old)
-                | (jnp.isinf(new) & jnp.isinf(old)
-                   & (jnp.sign(new) == jnp.sign(old)))
-                | (jnp.isnan(new) & jnp.isnan(old)))
-      ndiff = jnp.sum(jnp.logical_not(same).astype(jnp.int32))
-      o_ref[0] = new
-      it_ref[0, 0] = it_ref[0, 0] + 1
-      act_ref[0, 0] = (ndiff > 0).astype(jnp.int32)
+      # advance the iterate + flags in place, one slab at a time
+      def slab(s, ndiff):
+        old = o_ref[0, rows(s), :]
+        new = new_ref[rows(s), :]
+        if boolean:
+          same = new == old  # float {0,1} domain — plain equality is exact
+        else:
+          same = ((new == old)
+                  | (jnp.isinf(new) & jnp.isinf(old)
+                     & (jnp.sign(new) == jnp.sign(old)))
+                  | (jnp.isnan(new) & jnp.isnan(old)))
+        o_ref[0, rows(s), :] = new
+        return ndiff + jnp.sum(jnp.logical_not(same).astype(jnp.int32))
+
+      ndiff = jax.lax.fori_loop(0, nblk, slab, jnp.int32(0))
+      it_ref[r] = it_ref[r] + 1
+      act_ref[r] = (ndiff > 0).astype(jnp.int32)
 
   return fixpoint_kernel
 
@@ -174,6 +189,8 @@ def _chunk_call(c: Array, adj: Optional[Array], kv: Array, act: Array,
   sr = sr_mod.get(op)
   acc_dtype = c.dtype
   r, np_ = c.shape[0], c.shape[-1]
+  if np_ > MAX_N:
+    raise ValueError(f"megakernel serves padded n <= {MAX_N}, got {np_}")
   nblk = np_ // bm
   has_adj = adj is not None
   kernel = _make_fixpoint_kernel(sr, acc_dtype, nblk, bm, has_adj)
@@ -182,7 +199,8 @@ def _chunk_call(c: Array, adj: Optional[Array], kv: Array, act: Array,
     return pl.BlockSpec((1, np_, np_), lambda rr, gg, ii, *_: (rr, 0, 0))
 
   def flag_spec():
-    return pl.BlockSpec((1, 1), lambda rr, gg, ii, *_: (rr, 0))
+    # whole (R,) flag vectors in SMEM: scalar stores, indexed by request
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
   in_specs = [mat_spec()]
   operands = [c]
@@ -211,16 +229,19 @@ def _chunk_call(c: Array, adj: Optional[Array], kv: Array, act: Array,
       ),
       out_shape=[
           jax.ShapeDtypeStruct((r, np_, np_), acc_dtype),
-          jax.ShapeDtypeStruct((r, 1), jnp.int32),
-          jax.ShapeDtypeStruct((r, 1), jnp.int32),
+          jax.ShapeDtypeStruct((r,), jnp.int32),
+          jax.ShapeDtypeStruct((r,), jnp.int32),
       ],
-      compiler_params=_CompilerParams(
-          dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+      # the flag outputs are whole-array SMEM buffers shared by every grid
+      # step, so no grid dim may be split across cores
+      compiler_params=pltpu.CompilerParams(
+          dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+          vmem_limit_bytes=vmem_limit_bytes(np_, itemsize, has_adj)),
       cost_estimate=cost,
       interpret=interpret,
       name=f"simd2_fixpoint_{sr.name}",
   )(kv, act, it, glim, *operands)
-  return out, it_out[:, 0], act_out[:, 0]
+  return out, it_out, act_out
 
 
 class ChunkGeometry(NamedTuple):

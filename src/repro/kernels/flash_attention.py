@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams → CompilerParams across 0.4.x/0.5.x releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 Array = jax.Array
 
 _NEG_INF = -1e30
@@ -164,7 +160,7 @@ def flash_attention(q: Array, k: Array, v: Array, *,
           pltpu.VMEM((bq_, _LANES), jnp.float32),  # running denom
           pltpu.VMEM((bq_, d), jnp.float32),       # fp32 out accumulator
       ],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "parallel", "arbitrary")),
       interpret=interpret,
       name="flash_attention_fwd",

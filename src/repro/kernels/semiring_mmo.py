@@ -7,9 +7,11 @@ across the K grid dimension) whose ⊗/⊕ "ALU" is selected per instruction.
   * mma           → the block contraction is a real MXU ``jnp.dot``.
   * addnorm       → fused MXU rewrite in-kernel: −2·a@b plus row/col norm
                     rank-1 corrections (O(K·M·N) work on the MXU).
-  * min/max rings → VPU rank-u updates: the (bm, u, bn) ⊗-broadcast is
-                    ⊕-reduced over u, looping u-sized K slivers (u=8 matches
-                    the VPU sublane count).
+  * min/max rings → VPU rank-1 updates: K is loaded through the refs in
+                    lane-tile-aligned 128-wide chunks, and each chunk is
+                    ⊕-folded as 128 static (bm,1) ⊗ (1,bn) outer products —
+                    only static slices of loaded values, the form Mosaic
+                    lowers without dynamic lane relayouts.
   * orand         → runs in the float {0,1} domain with (max, min); the
                     wrapper restores bool.
 
@@ -31,19 +33,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import semiring as sr_mod
+from repro.core.mmo import MXU_PRECISION
 
 Array = jax.Array
-
-# jax renamed TPUCompilerParams → CompilerParams across 0.4.x/0.5.x releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
 
 # (pad_a, pad_b) per op with ⊗(pad_a, pad_b) == ⊕-identity (K-tail padding);
 # the table lives in core/semiring.py so the serving layer's shape bucketing
 # shares the exact same padding algebra.
 _PADS = sr_mod._CONTRACTION_PADS
 
-_SUBLANES = 8  # VPU sublane count — rank-u update width.
+_SUBLANES = 8  # VPU sublane count — row padding granularity.
+_LANES = 128   # lane tile — the K chunk width of the VPU contraction.
 
 
 def _float_ring(sr: sr_mod.Semiring):
@@ -53,45 +53,44 @@ def _float_ring(sr: sr_mod.Semiring):
   return sr.oplus, sr.otimes
 
 
-def _block_contract(sr: sr_mod.Semiring, a: Array, b: Array,
-                    acc_dtype, faithful: bool = False) -> Array:
+def rank1_fold(oplus, otimes, acc, a_c: Array, b_c: Array):
+  """⊕-fold ``a_c`` (bm, ck) ⊗ ``b_c`` (ck, bn) into ``acc`` (None = start
+  from the first term) as ``ck`` static rank-1 updates: column t of a_c
+  broadcast across lanes, row t of b_c across sublanes."""
+  for t in range(a_c.shape[1]):
+    term = otimes(a_c[:, t:t + 1], b_c[t:t + 1, :])
+    acc = term if acc is None else oplus(acc, term)
+  return acc
+
+
+def _block_contract(sr: sr_mod.Semiring, a_ref, b_ref, acc_dtype,
+                    faithful: bool = False) -> Array:
   """One (bm, bk) × (bk, bn) block contraction — the 'ALU' dispatch.
 
-  ``faithful=True`` forces the paper's ⊗-ALU semantics (VPU rank-u loop)
+  ``faithful=True`` forces the paper's ⊗-ALU semantics (VPU rank-1 loop)
   even for ops with an MXU rewrite — the paper-faithful baseline arm in
   EXPERIMENTS.md §Perf.
   """
   if sr.name == "mma" and not faithful:
-    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+    return jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+                   precision=MXU_PRECISION)
   if sr.name == "addnorm" and not faithful:
     # Σ(a−b)² = ‖a‖²·1ᵀ + 1·‖b‖²ᵀ − 2ab: MXU dot + rank-1 VPU corrections.
-    ab = jnp.dot(a, b, preferred_element_type=jnp.float32)
+    a, b = a_ref[...], b_ref[...]
+    ab = jnp.dot(a, b, preferred_element_type=jnp.float32,
+                 precision=MXU_PRECISION)
     a2 = jnp.sum(jnp.square(a.astype(jnp.float32)), axis=1, keepdims=True)
     b2 = jnp.sum(jnp.square(b.astype(jnp.float32)), axis=0, keepdims=True)
     return a2 - 2.0 * ab + b2
 
   oplus, otimes = _float_ring(sr)
-  bm, bk = a.shape
-  bn = b.shape[1]
-  u = min(_SUBLANES, bk)
-  nsub = bk // u
-
-  def body(j, acc):
-    a_s = jax.lax.dynamic_slice(a, (0, j * u), (bm, u)).astype(acc_dtype)
-    b_s = jax.lax.dynamic_slice(b, (j * u, 0), (u, bn)).astype(acc_dtype)
-    prod = otimes(a_s[:, :, None], b_s[None, :, :])  # (bm, u, bn)
-    part = prod[:, 0, :]
-    for t in range(1, u):  # u is tiny & static: unrolled ⊕-tree
-      part = oplus(part, prod[:, t, :])
-    return oplus(acc, part)
-
-  a0 = jax.lax.dynamic_slice(a, (0, 0), (bm, u)).astype(acc_dtype)
-  b0 = jax.lax.dynamic_slice(b, (0, 0), (u, bn)).astype(acc_dtype)
-  prod0 = otimes(a0[:, :, None], b0[None, :, :])
-  acc = prod0[:, 0, :]
-  for t in range(1, u):
-    acc = oplus(acc, prod0[:, t, :])
-  return jax.lax.fori_loop(1, nsub, body, acc) if nsub > 1 else acc
+  bk = a_ref.shape[1]
+  acc = None
+  for k0 in range(0, bk, _LANES):  # static, lane-tile-aligned K chunks
+    k1 = min(k0 + _LANES, bk)
+    acc = rank1_fold(oplus, otimes, acc, a_ref[:, k0:k1].astype(acc_dtype),
+                     b_ref[k0:k1, :].astype(acc_dtype))
+  return acc
 
 
 def _make_kernel(sr: sr_mod.Semiring, acc_dtype, has_c: bool, has_kv: bool,
@@ -115,7 +114,7 @@ def _make_kernel(sr: sr_mod.Semiring, acc_dtype, has_c: bool, has_kv: bool,
     def _init():
       # K-block 0 always runs: it both initializes o_ref and covers the
       # k_valid==0 case (a frozen request whose output the caller discards).
-      part = _block_contract(sr, a_ref[...], b_ref[...], acc_dtype, faithful)
+      part = _block_contract(sr, a_ref, b_ref, acc_dtype, faithful)
       if c_ref is not None:
         o_ref[...] = oplus(part, c_ref[...].astype(acc_dtype))
       else:
@@ -128,7 +127,7 @@ def _make_kernel(sr: sr_mod.Semiring, acc_dtype, has_c: bool, has_kv: bool,
 
     @pl.when(live)
     def _acc():
-      part = _block_contract(sr, a_ref[...], b_ref[...], acc_dtype, faithful)
+      part = _block_contract(sr, a_ref, b_ref, acc_dtype, faithful)
       o_ref[...] = oplus(o_ref[...], part)
 
   return kernel
@@ -210,7 +209,7 @@ def semiring_mmo(a: Array,
       in_specs=in_specs,
       out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
       out_shape=jax.ShapeDtypeStruct((mp, np_), acc_dtype),
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "parallel", "arbitrary")),
       interpret=interpret,
       name=f"simd2_{sr.name}",

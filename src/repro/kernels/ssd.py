@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams → CompilerParams across 0.4.x/0.5.x releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 Array = jax.Array
 
 
@@ -74,7 +70,7 @@ def ssd_intra_chunk(c: Array, b: Array, xh: Array, dt: Array, cum: Array,
       in_specs=[spec_qn, spec_qn, spec_qp, spec_q1, spec_q1],
       out_specs=spec_qp,
       out_shape=jax.ShapeDtypeStruct((bz, h, q, p), jnp.float32),
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "parallel")),
       interpret=interpret,
       name="ssd_intra_chunk",

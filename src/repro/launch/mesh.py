@@ -12,13 +12,14 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.distributed import make_mesh
 from repro.models.common import Parallelism
 
 
 def make_production_mesh(*, multi_pod: bool = False):
   shape = (2, 16, 16) if multi_pod else (16, 16)
   axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-  return jax.make_mesh(shape, axes)
+  return make_mesh(shape, axes)
 
 
 def make_parallelism(*, multi_pod: bool = False, fsdp: bool = True,
@@ -39,4 +40,4 @@ def make_host_mesh(n_devices: int = 0, model: int = 2):
   """Small mesh over host devices (tests / examples)."""
   n = n_devices or len(jax.devices())
   model = min(model, n)
-  return jax.make_mesh((n // model, model), ("data", "model"))
+  return make_mesh((n // model, model), ("data", "model"))

@@ -102,7 +102,9 @@ def warmup(engine: MMOEngine, rng: np.random.Generator, sizes, n: int = 40):
 
 def main(argv=None):
   from repro.analysis.sanitize import maybe_enable_sanitize
+  from repro.compile_cache import enable_compile_cache
   maybe_enable_sanitize()  # REPRO_SANITIZE=1: debug_nans + analyzer preflight
+  enable_compile_cache()
   ap = argparse.ArgumentParser()
   ap.add_argument("--rate", type=float, default=40.0,
                   help="mean arrival rate (problems/s)")
@@ -240,7 +242,8 @@ def main(argv=None):
       ap.error(f"--mesh {args.mesh} needs {need} devices, host has {have} "
                f"(on CPU: XLA_FLAGS=--xla_force_host_platform_device_count="
                f"{need})")
-    mesh = jax.make_mesh(dims, ("data", "model"))
+    from repro.core.distributed import make_mesh
+    mesh = make_mesh(dims)
     print(f"[serve_mmo] mesh data={dims[0]} × model={dims[1]} "
           f"schedule={args.schedule} shard_flops={args.shard_flops:g}")
   elif args.schedule != "auto":
@@ -418,6 +421,12 @@ def main(argv=None):
   if not args.no_warmup and misses_during:
     print(f"[serve_mmo] WARNING: {misses_during} compiles during the "
           f"measured window (cold buckets)")
+  if outcomes["failed"] and injector is None:
+    # with no faults injected, a failed request is a real engine or kernel
+    # failure — the run must not look healthy to whatever launched it
+    print(f"[serve_mmo] ERROR: {outcomes['failed']} requests failed with no "
+          f"faults injected", file=sys.stderr)
+    return 1
   return 0
 
 

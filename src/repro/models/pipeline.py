@@ -21,15 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):
-  shard_map = jax.shard_map
-else:  # pragma: no cover — older jax keeps it under experimental
-  from jax.experimental.shard_map import shard_map
-
-# jax.lax.pvary only exists on newer jax (varying-axis annotations for
-# shard_map rep-checking); older versions don't need the annotation.
-pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 Array = jax.Array
 
 
@@ -61,8 +52,8 @@ def pipeline(stage_fn: Callable, mesh: Mesh, *, axis: str = "stage",
     m = xs.shape[0]
     t_total = m + n_stage - 1
     zero = jnp.zeros_like(xs[0])
-    outs0 = pvary(jnp.zeros_like(xs), (axis,))
-    buf0 = pvary(zero, (axis,))
+    outs0 = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
+    buf0 = jax.lax.pcast(zero, (axis,), to="varying")
 
     def tick(t, carry):
       buf, outs = carry
@@ -86,8 +77,8 @@ def pipeline(stage_fn: Callable, mesh: Mesh, *, axis: str = "stage",
 
   in_spec = in_spec if in_spec is not None else P(axis)
   x_spec = x_spec if x_spec is not None else P()
-  return shard_map(spmd, mesh=mesh, in_specs=(in_spec, x_spec),
-                   out_specs=x_spec)
+  return jax.shard_map(spmd, mesh=mesh, in_specs=(in_spec, x_spec),
+                       out_specs=x_spec)
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:

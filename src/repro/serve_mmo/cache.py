@@ -87,6 +87,12 @@ class ExecutableCache:
                                            compile_s=elapsed)
     return compiled
 
+  def executables(self) -> dict:
+    """exec key → compiled program, for callers that inspect what ran (the
+    output shardings of a mesh-placed bucket, say)."""
+    with self._lock:
+      return {k: e.compiled for k, e in self._entries.items()}
+
   def stats(self) -> dict:
     with self._lock:
       return {

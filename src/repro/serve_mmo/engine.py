@@ -31,6 +31,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from repro.kernels import closure_megakernel as _megakernel
 from repro.serve_mmo import batching
 from repro.serve_mmo.admission import AdmissionController
 from repro.serve_mmo.api import (DeadlineExceededError, MMOFuture, MMOResult,
@@ -85,6 +86,11 @@ class EngineStats:
   cache: dict
   rejected: int = 0
   expired: int = 0
+  # closure requests above the megakernel's size cap that an arena-mode or
+  # megakernel-pinned engine served on the per-iteration batch path
+  over_cap: int = 0
+  # (bucket label, backend, schedule) → batches (arena: ticks) that arm ran
+  arms: dict = dataclasses.field(default_factory=dict)
 
   def percentile(self, q: float) -> float:
     if len(self.latencies_s) == 0:
@@ -103,6 +109,7 @@ class EngineStats:
     return (f"completed={self.completed} batches={self.batches} "
             f"mean_batch={self.mean_batch:.2f} {lat} "
             f"rejected={self.rejected} expired={self.expired} "
+            f"over_cap={self.over_cap} "
             f"cache_hits={self.cache['hits']} "
             f"cache_misses={self.cache['misses']}")
 
@@ -191,6 +198,10 @@ class MMOEngine:
   (pinned on the shared parity corpus in tests/test_arena.py); what
   changes is the waiting: an urgent arrival joins the running fixpoint at
   the next tick boundary instead of queueing behind a full bucket cycle.
+  Closure buckets above the megakernel's size cap
+  (``closure_megakernel.MAX_N``) stay on the batch path in either mode and
+  are counted in ``stats().over_cap``; ``resolve_backend`` never hands
+  them to the megakernel.
   """
 
   def __init__(self, *, backend: str = "auto", max_batch: int = 8,
@@ -288,6 +299,8 @@ class MMOEngine:
     self._batches = 0
     self._rejected = 0
     self._expired = 0
+    self._over_cap = 0
+    self._arms: dict = {}  # (bucket label, backend, schedule) → batches
     self._next_id = 0
     self._pending: dict[int, MMOFuture] = {}
     self._inflight: set[int] = set()  # popped from the queue, executing now
@@ -328,8 +341,7 @@ class MMOEngine:
         # arena-mode closure buckets execute on the arena arm, so their
         # static prior prices slot-seconds there (the fused-chunk roofline —
         # see tuning/cost_table.py), not whatever the batch path would pick
-        backend = ("arena" if self.mode == "arena" and key.kind == "closure"
-                   else self.backend)
+        backend = "arena" if self._arena_serves(key) else self.backend
         _, _, s = _dispatch.contraction_seconds(
             key.op, m, k, n, key.dtypes[0], backend=backend,
             table=self.cost_table)
@@ -353,7 +365,7 @@ class MMOEngine:
     contraction_s, trips = self._static_point(key)
     if not self.adaptive:
       return Estimate(contraction_s * trips, "static")
-    if self.mode == "arena" and key.kind == "closure":
+    if self._arena_serves(key):
       # the arena's estimator cell holds measured slot-seconds per request
       # (admit → evict), observed at eviction — exactly the residency the
       # admission controller charges for
@@ -425,6 +437,15 @@ class MMOEngine:
     log2(max_batch)+1 executables instead of one per arrival count."""
     return bucket_dim(r, 1)
 
+  @staticmethod
+  def _megakernel_serves(key) -> bool:
+    """Whether the fused megakernel (and so the arena) can hold this bucket:
+    closures whose bucket dim is within the kernel's VMEM-resident cap."""
+    return key.kind == "closure" and key.shape[0] <= _megakernel.MAX_N
+
+  def _arena_serves(self, key) -> bool:
+    return self.mode == "arena" and self._megakernel_serves(key)
+
   def resolve_backend(self, key) -> tuple:
     """(backend, block cfg) for one bucket — the dispatch decision.
 
@@ -438,17 +459,22 @@ class MMOEngine:
     with self._lock:
       dec = self._decisions.get(key)
       if dec is None:
-        if self.backend != "auto":
+        if self.backend == "megakernel" and not self._megakernel_serves(key):
+          # above the cap the fused arm cannot compile; the per-iteration
+          # Pallas path runs the same kernel family one contraction a time
+          dec = ("pallas", ())
+        elif self.backend != "auto":
           dec = (self.backend, ())
         else:
           from repro.tuning import dispatch as _dispatch
           m, k, n = contract_shape(key)
           # closure buckets own a whole fixpoint, so the fused 'megakernel'
           # arm competes for them (and only them: a single-contraction
-          # bucket can't run it).  The choice flows into _exec_key via the
-          # (backend, block) slots, so cached executables stay distinct.
-          pool = (_dispatch.CLOSURE_BACKENDS if key.kind == "closure"
-                  else None)
+          # bucket can't run it) up to its size cap.  The choice flows into
+          # _exec_key via the (backend, block) slots, so cached executables
+          # stay distinct.
+          pool = (_dispatch.closure_backends(key.shape[0])
+                  if key.kind == "closure" else None)
           d = _dispatch.resolve(key.op, m, k, n, key.dtypes[0],
                                 table=self.cost_table, backends=pool)
           dec = (d.backend, d.cfg)
@@ -621,8 +647,9 @@ class MMOEngine:
 
   def _arena_admit_phase(self) -> bool:
     """Move queued closure requests into free arena slots, respecting the
-    policy's bucket order.  Returns True when the queue head is non-closure
-    (the caller then runs one batch step).  Admission stops at a full
+    policy's bucket order.  Returns True when the queue head is not arena
+    traffic — non-closure, or a closure above the megakernel's cap (the
+    caller then runs one batch step).  Admission stops at a full
     arena — its slots free up at the next sweep, so progress is guaranteed
     without ever popping more requests than there are slots."""
     while True:
@@ -631,7 +658,7 @@ class MMOEngine:
         key = self.scheduler.peek_bucket(now)
         if key is None:
           return False
-        if key.kind != "closure":
+        if not self._arena_serves(key):
           return True
         arena = self._arena_for_locked(key)
         free = arena.free_slots()
@@ -695,6 +722,8 @@ class MMOEngine:
     with self._lock:
       self._arena_failures[key] = 0
       self._batches += 1
+      arm = (label, _ARENA_ARM[0], _ARENA_ARM[2])
+      self._arms[arm] = self._arms.get(arm, 0) + 1
       self.metrics.on_batch(key, host_s=0.0, device_s=t1 - t0, h2d_bytes=0)
     if self.tracer.enabled:
       self.tracer.arena_tick(label, live=len(rids), evicted=len(evictions),
@@ -1045,6 +1074,11 @@ class MMOEngine:
           iterations=info["iters_live"], emit_pick=emit_pick)
     with self._lock:
       self._batches += 1
+      arm = (bucket_label(key), info["backend"], info["schedule"])
+      self._arms[arm] = self._arms.get(arm, 0) + 1
+      if (key.kind == "closure" and not self._megakernel_serves(key)
+          and (self.mode == "arena" or self.backend == "megakernel")):
+        self._over_cap += len(reqs)
       self.metrics.on_batch(
           key,
           host_s=((info["stacked_s"] - info["start_s"])
@@ -1276,7 +1310,7 @@ class MMOEngine:
     seen = {request_bucket(req, min_bucket) for req in sample_reqs}
     before = self.cache.misses
     for key in seen:
-      if self.mode == "arena" and key.kind == "closure":
+      if self._arena_serves(key):
         # arena buckets compile their three slot programs instead of the
         # pow2 batch ladder — after this, admissions/ticks/evictions replay
         # stored executables (the zero-retrace guarantee test_arena pins)
@@ -1353,6 +1387,7 @@ class MMOEngine:
       recs = list(self._records)
       batches = self._batches
       rejected, expired = self._rejected, self._expired
+      over_cap, arms = self._over_cap, dict(self._arms)
     lat = np.asarray([r.latency_s for r in recs], dtype=np.float64)
     return EngineStats(
         completed=len(recs),
@@ -1362,6 +1397,8 @@ class MMOEngine:
         cache=self.cache.stats(),
         rejected=rejected,
         expired=expired,
+        over_cap=over_cap,
+        arms=arms,
     )
 
   def reset_stats(self):
@@ -1370,3 +1407,5 @@ class MMOEngine:
       self._batches = 0
       self._rejected = 0
       self._expired = 0
+      self._over_cap = 0
+      self._arms.clear()

@@ -43,9 +43,12 @@ DEFAULT_BACKENDS = ("xla", "vector", "pallas", "megakernel")
 
 def _megakernel_point_ok(op: str, shape) -> bool:
   """The fused-fixpoint arm only exists for closure-shaped points: square
-  contractions on rings with a ⊗-identity (closure is refused elsewhere)."""
+  contractions on rings with a ⊗-identity (closure is refused elsewhere),
+  up to the kernel's size cap."""
+  from repro.kernels import closure_megakernel as mk
   m, k, n = bucket_shape(shape)
-  return m == k == n and sr_mod.get(op).otimes_identity is not None
+  return (m == k == n and m <= mk.MAX_N
+          and sr_mod.get(op).otimes_identity is not None)
 
 
 def _device_label() -> str:
@@ -248,10 +251,9 @@ def tune_mesh(*,
   if table is None:
     table = CostTable(device="prior-only" if dry_prior else _device_label())
   if not dry_prior:
+    from repro.core.distributed import make_mesh, schedule_fits
     if mesh is None:
-      import jax
-      mesh = jax.make_mesh(dims, ("data", "model"))
-    from repro.core.distributed import schedule_fits
+      mesh = make_mesh(dims)
   for op in ops:
     op_dtypes = ("bool",) if sr_mod.get(op).boolean else dtypes
     for shape in shapes:

@@ -85,6 +85,15 @@ def use_cost_table(table: Union[CostTable, str, None]):
     set_cost_table(prev)
 
 
+def closure_backends(n: int) -> tuple:
+  """The closure-bucket backend pool at bucket dim ``n``: the fused
+  'megakernel' arm competes only up to its VMEM-resident size cap."""
+  from repro.kernels import closure_megakernel as mk
+  if n <= mk.MAX_N:
+    return CLOSURE_BACKENDS
+  return tuple(b for b in CLOSURE_BACKENDS if b != "megakernel")
+
+
 def contraction_seconds(op: str, m: int, k: int, n: int, dtype, *,
                         backend: str = "auto",
                         table: Optional[CostTable] = None) -> tuple:

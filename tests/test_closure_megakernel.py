@@ -240,3 +240,64 @@ def test_engine_mmo_bucket_never_sees_megakernel():
   eng.run_until_idle()
   assert all(b != "megakernel" for b, _ in eng._decisions.values())
   assert fut.done()
+
+
+@pytest.fixture
+def small_cap(monkeypatch):
+  """Shrink the megakernel's size cap to 16 so a CPU-sized closure sits
+  above it (the engine and dispatch read the cap at routing time)."""
+  from repro.kernels import closure_megakernel as mk
+  monkeypatch.setattr(mk, "MAX_N", 16)
+  return 16
+
+
+def test_megakernel_refuses_padded_n_above_cap(small_cap):
+  adj = _rand_adj("minplus", 20, 1)
+  with pytest.raises(ValueError, match="megakernel serves padded n"):
+    cl_mod.batched_leyzorek_closure(adj, op="minplus",
+                                    fixpoint_backend="megakernel",
+                                    interpret=True)
+
+
+def test_engine_never_routes_megakernel_above_cap(small_cap):
+  """A cost table where the fused arm wins every closure bucket: auto keeps
+  it for the bucket at the cap and drops it above; a megakernel-pinned
+  engine serves the above-cap bucket on the per-iteration Pallas path."""
+  from repro.serve_mmo import MMOEngine, apsp_request
+  from repro.serve_mmo.scheduler import request_bucket
+  from repro.tuning import CostTable
+  table = CostTable(device="test")
+  for nb in (16, 32):
+    table.record("minplus", (nb,) * 3, "float32", "xla", (), 1.0,
+                 source="measured")
+    table.record("minplus", (nb,) * 3, "float32", "megakernel", (4,), 1e-9,
+                 source="measured")
+  at_cap = request_bucket(apsp_request(_line_graph(12, seed=1)))
+  above = request_bucket(apsp_request(_line_graph(20, seed=2)))
+  auto = MMOEngine(backend="auto", cost_table=table)
+  assert auto.resolve_backend(at_cap)[0] == "megakernel"
+  assert auto.resolve_backend(above)[0] == "xla"
+  pinned = MMOEngine(backend="megakernel")
+  assert pinned.resolve_backend(at_cap)[0] == "megakernel"
+  assert pinned.resolve_backend(above)[0] == "pallas"
+
+
+def test_arena_serves_closures_above_cap_on_batch_path(small_cap):
+  """Arena mode admits the bucket at the cap into slots and serves the one
+  above it on the batch path — same results as batch mode, counted in
+  stats().over_cap, each bucket on its own arm."""
+  from repro.serve_mmo import MMOEngine, apsp_request
+  graphs = [_line_graph(12, seed=3), _line_graph(20, seed=4)]
+  arena = MMOEngine(backend="pallas", mode="arena")
+  batch = MMOEngine(backend="pallas")
+  got = [arena.submit(apsp_request(w)) for w in graphs]
+  want = [batch.submit(apsp_request(w)) for w in graphs]
+  arena.run_until_idle()
+  batch.run_until_idle()
+  for g, w in zip(got, want):
+    np.testing.assert_array_equal(g.result().value, w.result().value)
+    assert g.result().extras["iterations"] == w.result().extras["iterations"]
+  st = arena.stats()
+  assert st.over_cap == 1
+  assert sorted(b for _, b, _ in st.arms) == ["arena", "pallas"]
+  assert "over_cap=1" in st.summary()

@@ -15,8 +15,8 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core.distributed import (distributed_leyzorek, mmo_kspan,
-                                        ring_mmo, summa_mmo)
+    from repro.core.distributed import (distributed_leyzorek, make_mesh,
+                                        mmo_kspan, ring_mmo, summa_mmo)
     from repro.core.mmo import mmo_reference
     from repro.core import prepare_adjacency
     from repro.models import zoo, common as cm
@@ -24,7 +24,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.train import AdamWConfig, init_opt_state, make_train_step
     from repro.data import DataConfig, SyntheticLM
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4))
     rng = np.random.default_rng(1)
 
     # --- 1. all three distributed schedules == reference, every op class ---

@@ -14,9 +14,10 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
+    from repro.core.distributed import make_mesh
     from repro.models.pipeline import bubble_fraction, pipeline, split_stages
 
-    mesh = jax.make_mesh((4, 2), ("stage", "data"))
+    mesh = make_mesh((4, 2), ("stage", "data"))
     S, LPS, D, M, MB = 4, 3, 16, 6, 2   # stages, layers/stage, dim, micro, mb
     L = S * LPS
     rng = np.random.default_rng(0)

@@ -563,3 +563,26 @@ def test_breaker_disabled_keeps_failing_in_place():
     with pytest.raises(InjectedFault):
       fut.result()
   assert eng.observability_state()["breakers"] == []
+
+
+def test_serve_launcher_exit_code_reports_failed_requests(monkeypatch):
+  """The open-loop launcher with breakers, retries and bisection off: a fault
+  injector wired in behind the CLI fails every request, and the run exits 1
+  — undeclared failures must not look healthy.  The same faults declared
+  with --inject-faults are chaos the run asked for, and it exits 0."""
+  from repro import compile_cache
+  from repro.launch import serve_mmo
+  from repro.serve_mmo import MMOEngine, parse_fault_spec
+  monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+
+  def rigged(**kw):
+    kw["faults"] = parse_fault_spec("execute:persistent")
+    return MMOEngine(**kw)
+
+  argv = ["--rate", "20", "--duration", "0.3", "--sizes", "8",
+          "--no-warmup", "--transient-retries", "0", "--no-bisect",
+          "--breaker-threshold", "0"]
+  monkeypatch.setattr(serve_mmo, "MMOEngine", rigged)
+  assert serve_mmo.main(argv) == 1
+  monkeypatch.setattr(serve_mmo, "MMOEngine", MMOEngine)
+  assert serve_mmo.main(argv + ["--inject-faults", "execute:persistent"]) == 0

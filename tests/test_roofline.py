@@ -14,6 +14,7 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.distributed import make_mesh
     from repro.roofline.hlo_walk import module_cost
 
     M = 256
@@ -43,7 +44,7 @@ _SCRIPT = textwrap.dedent("""
     print("FLOPS_OK")
 
     # collective accounting: K-sharded matmul → one all-reduce of (M,M) f32
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     def f(a, b):
         return a @ b
     j = jax.jit(f, in_shardings=(NamedSharding(mesh, P(None, "model")),

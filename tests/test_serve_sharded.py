@@ -17,6 +17,7 @@ import pytest
 import jax
 
 from repro.apps import graphs, solvers
+from repro.core.distributed import make_mesh
 from repro.serve_mmo import MMOEngine, apsp_request, mmo_request
 from repro.serve_mmo.scheduler import request_bucket
 from repro.tuning import (CostTable, prior_seconds, resolve,
@@ -26,7 +27,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _mesh11():
-  return jax.make_mesh((1, 1), ("data", "model"))
+  return make_mesh((1, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -199,11 +200,12 @@ _SCRIPT = textwrap.dedent("""
     from repro.core import mmo_batched, mmo_reference
     from repro.core import pad_adjacency, prepare_adjacency
     from repro.core.closure import batched_leyzorek_closure
-    from repro.core.distributed import (mmo_kspan_batched, ring_mmo_batched,
+    from repro.core.distributed import (make_mesh, mmo_kspan_batched,
+                                        ring_mmo_batched,
                                         sharded_closure_batched,
                                         summa_mmo_batched)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4))
     rng = np.random.default_rng(0)
     R, M, K, N = 3, 16, 32, 24
 
