@@ -1,0 +1,8 @@
+"""Host time per answered request in the batching layer: pad-and-stack
+(with the copy the device reads) and split-results (flight recorder spans
+``pad_and_stack`` and ``split_results``), in ms."""
+from bench import layers
+
+
+def read(run):
+  return layers.host_ms_per_request(run, ("pad_and_stack", "split_results"))

@@ -1,0 +1,7 @@
+"""Required time of the window's work at the chip's peak over the device
+time of the closure megakernel (``simd2_fixpoint_<ring>`` ops), in %."""
+from bench import layers
+
+
+def read(run):
+  return layers.kernel_roofline_pct(run, r"simd2_fixpoint_")
