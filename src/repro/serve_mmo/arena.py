@@ -62,6 +62,7 @@ from repro.kernels.closure_megakernel import (chunk_geometry, fixpoint_chunk,
                                               fixpoint_iters)
 from repro.serve_mmo.api import ProblemRequest
 from repro.serve_mmo.cache import ExecutableCache
+from repro.serve_mmo.metrics import bucket_label
 from repro.serve_mmo.scheduler import BucketKey
 
 __all__ = ["DEFAULT_CAPACITY", "DEFAULT_ARENA_G", "Eviction", "RequestArena"]
@@ -138,6 +139,9 @@ class RequestArena:
     self._admitted = 0
     self._evicted = 0
     self._ticks = 0
+    # clock reading when the last sweep's flags reached the host: where the
+    # host stopped waiting on the tick and began reading evicted slots out
+    self.flags_s: Optional[float] = None
     self._program_specs = self._build_program_specs()
 
   # -- AOT programs ----------------------------------------------------------
@@ -203,7 +207,8 @@ class RequestArena:
     make_fn, abstract = self._program_specs[name]
     return self.cache.get_or_compile(
         ("arena", self.key, name, self.capacity, self.g, self.max_iters),
-        make_fn, abstract)
+        make_fn, abstract,
+        label=f"arena/{bucket_label(self.key)}/{name}/c{self.capacity}")
 
   def prewarm(self) -> None:
     """Compile all three programs; after this, arena traffic never retraces
@@ -285,6 +290,7 @@ class RequestArena:
     with self._lock:
       act = np.asarray(self._act)  # blocks on the tick — the one sync point
       it = np.asarray(self._it)
+      self.flags_s = self._clock()
       read = self._compiled("read")
       evictions = []
       for slot, req in enumerate(self._slots):

@@ -14,15 +14,21 @@ hundreds of milliseconds and must not stall the serving loop's hits on other
 keys.  Two threads missing the same key may therefore both compile; the
 first insert wins, the loser's work is discarded, and the counters stay
 consistent (misses counts compile *attempts*, so `misses >= executables`).
+
+With a ``recorder`` (the engine's ``FlightRecorder``) every miss also
+leaves a ``compile`` span — the key's label and the seconds it took — so
+the trace says which program compiled, and when.
 """
 from __future__ import annotations
 
 import dataclasses
 import threading
 import time
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
+
+from repro.serve_mmo.observability import FlightRecorder
 
 
 @dataclasses.dataclass
@@ -33,7 +39,8 @@ class CacheEntry:
 
 
 class ExecutableCache:
-  def __init__(self):
+  def __init__(self, recorder: Optional[FlightRecorder] = None):
+    self.recorder = recorder
     self._lock = threading.Lock()
     self._entries: dict = {}
     self._misses = 0
@@ -61,11 +68,13 @@ class ExecutableCache:
     with self._lock:
       return len(self._entries)
 
-  def get_or_compile(self, exec_key, make_fn: Callable, args) -> Callable:
+  def get_or_compile(self, exec_key, make_fn: Callable, args,
+                     label: Optional[str] = None) -> Callable:
     """Return the compiled program for ``exec_key``, compiling on first use.
 
     ``make_fn`` builds the pure function; ``args`` are example (or abstract)
-    operands fixing shapes/dtypes.
+    operands fixing shapes/dtypes; ``label`` names the program in the
+    ``compile`` span (default: the key's text).
     """
     with self._lock:
       entry = self._entries.get(exec_key)
@@ -73,11 +82,19 @@ class ExecutableCache:
         entry.hits += 1
         return entry.compiled
       self._misses += 1
+    recorder = self.recorder
+    traced = recorder is not None and recorder.enabled
+    span_t0 = recorder.now() if traced else 0.0
     t0 = time.perf_counter()
     abstract = tuple(
         jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
     compiled = jax.jit(make_fn()).lower(*abstract).compile()
     elapsed = time.perf_counter() - t0
+    if traced:
+      recorder.span("compile", cat="cache", t0_s=span_t0,
+                    t1_s=recorder.now(),
+                    args={"key": str(exec_key) if label is None else label,
+                          "seconds": elapsed})
     with self._lock:
       entry = self._entries.get(exec_key)
       if entry is not None:  # lost the compile race: first insert wins

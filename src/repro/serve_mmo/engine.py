@@ -267,7 +267,7 @@ class MMOEngine:
     self.metrics = ServeMetrics(clock=self._clock, window=metrics_window)
     self.tracer = tracer if tracer is not None else FlightRecorder(
         capacity=trace_capacity, clock=self._clock, enabled=trace)
-    self.cache = ExecutableCache()
+    self.cache = ExecutableCache(recorder=self.tracer)
     # -- fault tolerance (DESIGN.md §Fault tolerance) -----------------------
     if transient_retries < 0:
       raise ValueError(f"transient_retries must be >= 0, "
@@ -545,6 +545,11 @@ class MMOEngine:
     return (key, rb, backend, block, schedule,
             None if schedule == "local" else self._mesh_sig)
 
+  @staticmethod
+  def _exec_label(key, rb: int, backend: str, schedule: str) -> str:
+    """The executable's name in its ``compile`` span."""
+    return f"{bucket_label(key)}/b{rb}/{backend}/{schedule}"
+
   def _expire_locked(self, reqs) -> None:
     """Fail requests whose deadline passed while queued (or that the policy
     failed fast as hopeless).  Engine lock held by the caller."""
@@ -672,9 +677,11 @@ class MMOEngine:
         for r in taken:
           self.admission.on_dequeue(r)
           self._inflight.add(r.request_id)
-          slot = arena.admit(r, now=self._clock())
+          t0 = self._clock()
+          slot = arena.admit(r, now=t0)
           if self.tracer.enabled:
-            self.tracer.arena_admit(r.request_id, slot=slot, bucket=label)
+            self.tracer.arena_admit(r.request_id, slot=slot, bucket=label,
+                                    t0_s=t0, t_s=self._clock())
 
   def _arena_tick_phase(self) -> int:
     """Tick every arena with live slots, then complete its evictions."""
@@ -694,6 +701,7 @@ class MMOEngine:
     if not rids:
       return 0
     t0 = self._clock()
+    launched = []  # the clock when the chunk launch returned
     try:
       slow_rule = None
       if self.faults is not None:
@@ -707,6 +715,7 @@ class MMOEngine:
         if slow_rule is not None:
           time.sleep(slow_rule.delay_s)
         arena.tick()
+        launched.append(self._clock())
         return arena.sweep()  # blocks on the tick's device flags
 
       evictions = self._call_with_watchdog(run, label)
@@ -725,10 +734,21 @@ class MMOEngine:
       arm = (label, _ARENA_ARM[0], _ARENA_ARM[2])
       self._arms[arm] = self._arms.get(arm, 0) + 1
       self.metrics.on_batch(key, host_s=0.0, device_s=t1 - t0, h2d_bytes=0)
+    finish = None
+    done = []
+    completed = 0
+    if evictions:
+      f0 = self._clock()
+      completed = self._finish_evictions(key, arena, evictions, label, done)
+      finish = (f0, self._clock())
     if self.tracer.enabled:
+      # one emission per tick: its phases, the answered evictions and the
+      # finish, after the futures are fulfilled
       self.tracer.arena_tick(label, live=len(rids), evicted=len(evictions),
-                             g=arena.g, t0_s=t0, t1_s=t1)
-    return self._finish_evictions(key, arena, evictions, label)
+                             g=arena.g, t0_s=t0, t1_s=t1,
+                             launched_s=launched[0] if launched else None,
+                             flags_s=arena.flags_s, finish=finish, done=done)
+    return completed
 
   def _arena_tick_failed(self, key, arena, exc) -> None:
     """Tick failure recovery: slots stay resident under the transient-retry
@@ -766,12 +786,14 @@ class MMOEngine:
                                 "error": type(exc).__name__})
     self._fail_requests(key, victims, exc)
 
-  def _finish_evictions(self, key, arena, evictions, label) -> int:
+  def _finish_evictions(self, key, arena, evictions, label, done) -> int:
     """Turn evictions into results: per-request validation, final
     accounting, and estimator feedback.  The estimator observes measured
     slot-seconds (admit → evict, rb=1) — the per-request residency QoS
     predictions price — plus the measured iteration count, mirroring the
-    batch path's two feedback signals."""
+    batch path's two feedback signals.  Appends (request id, slot,
+    iterations, completion time) of each answered request to ``done``, for
+    the tick's trace emission."""
     completed = 0
     for ev in evictions:
       r = ev.request
@@ -808,10 +830,7 @@ class MMOEngine:
       self.estimator.observe_iterations(key, [int(ev.iterations)])
       self.estimator.observe_batch(key, _ARENA_ARM[0], _ARENA_ARM[2], 1,
                                    now - ev.admit_s)
-      if self.tracer.enabled:
-        self.tracer.request_end(r.request_id, "done", executing=True,
-                                args={"slot": ev.slot,
-                                      "iterations": int(ev.iterations)})
+      done.append((r.request_id, ev.slot, int(ev.iterations), now))
       with self._lock:
         self._inflight.discard(r.request_id)
         self._records.append(RequestRecord(
@@ -947,7 +966,7 @@ class MMOEngine:
           lambda: batching.make_batch_fn(key, backend=backend, block=block,
                                          interpret=self.interpret,
                                          mesh=self.mesh, schedule=schedule),
-          stacked)
+          stacked, label=self._exec_label(key, rb, backend, schedule))
       cache_hit = self.cache.misses == misses_before
       # estimator observations start AFTER compilation: a cache-miss batch
       # must not feed trace+compile time (orders of magnitude above steady
@@ -955,6 +974,7 @@ class MMOEngine:
       executed_s = self._clock()
       phase = "execute"
       exec_fault = slow_rule = None
+      dispatched = []  # the clock when the compiled call returned
       if faults is not None:
         exec_fault = faults.check("execute", label=label, backend=backend,
                                   request_ids=rids)
@@ -967,6 +987,7 @@ class MMOEngine:
         if slow_rule is not None:
           time.sleep(slow_rule.delay_s)
         out = compiled(*stacked)
+        dispatched.append(self._clock())
         # block on the device result here so the device-compute window
         # (executed_s → device_s) is honest: jax dispatch is async, and
         # without the sync the first np.asarray below would absorb the
@@ -980,6 +1001,7 @@ class MMOEngine:
       # free downstream)
       out = (tuple(np.asarray(x) for x in out)
              if isinstance(out, (tuple, list)) else np.asarray(out))
+      fetched_s = self._clock()
       if faults is not None:
         nf = faults.check("nonfinite", label=label, backend=backend,
                           request_ids=rids)
@@ -1045,6 +1067,7 @@ class MMOEngine:
                                  completed_s - executed_s)
     info = {"start_s": attempt_s, "stacked_s": stacked_s,
             "executed_s": executed_s, "device_s": device_s,
+            "dispatched_s": dispatched[0], "fetched_s": fetched_s,
             "completed_s": completed_s, "rb": rb, "h2d_bytes": h2d_bytes,
             "cache_hit": cache_hit, "backend": backend,
             "schedule": schedule, "iters_live": iters_live}
@@ -1059,8 +1082,8 @@ class MMOEngine:
     time), while the batch phase spans use the attempt's own timestamps."""
     completed_s = info["completed_s"]
     if self.tracer.enabled:
-      # one call carries the whole attempt's event set (phase spans,
-      # iteration slices, member picks + dones) so the steady-state tracing
+      # one call carries the whole attempt's event set (phase spans and
+      # their children, member picks + dones) so the steady-state tracing
       # cost is one lock acquisition per batch, not per request
       self.tracer.batch_complete(
           label=bucket_label(key), scheduled_s=info["start_s"],
@@ -1071,7 +1094,8 @@ class MMOEngine:
           h2d_bytes=info["h2d_bytes"], cache_hit=info["cache_hit"],
           request_ids=[r.request_id for r in reqs],
           arrivals_s=[r.arrival_s for r in reqs],
-          iterations=info["iters_live"], emit_pick=emit_pick)
+          iterations=info["iters_live"], emit_pick=emit_pick,
+          dispatched_s=info["dispatched_s"], fetched_s=info["fetched_s"])
     with self._lock:
       self._batches += 1
       arm = (bucket_label(key), info["backend"], info["schedule"])
@@ -1326,7 +1350,8 @@ class MMOEngine:
             lambda s=schedule: batching.make_batch_fn(
                 key, backend=backend, block=block, interpret=self.interpret,
                 mesh=self.mesh, schedule=s),
-            batching.abstract_batch(key, rb))
+            batching.abstract_batch(key, rb),
+            label=self._exec_label(key, rb, backend, schedule))
         if rb >= max_batch:
           break
         rb = self._batch_bucket(min(2 * rb, max_batch))
@@ -1336,12 +1361,14 @@ class MMOEngine:
 
   def start(self):
     """Spawn the background serving thread (idempotent; re-arms submit
-    after a stop())."""
+    after a stop()).  With tracing on, garbage collections are recorded as
+    ``gc_pause`` spans until ``stop()``."""
     with self._lock:
       self._stopped = False
       if self._running:
         return
       self._running = True
+    self.tracer.watch_gc()
     self._thread = threading.Thread(target=self._loop, name="mmo-serve",
                                     daemon=True)
     self._thread.start()
@@ -1369,15 +1396,22 @@ class MMOEngine:
     if self._thread is not None:
       self._thread.join()
       self._thread = None
+    self.tracer.unwatch_gc()
 
   def _loop(self):
     while True:
       with self._work:
+        t0 = None  # set when the loop blocks for want of work
         while (self._running and len(self.scheduler) == 0
                and not self._arena_live_locked()):
+          if t0 is None:
+            t0 = self._clock()
           self._work.wait()
         if not self._running:
           return
+        t1 = self._clock() if t0 is not None else None
+      if t0 is not None and self.tracer.enabled:
+        self.tracer.span("loop_wait", cat="engine", t0_s=t0, t1_s=t1)
       self.step()
 
   # -- stats -----------------------------------------------------------------

@@ -3,9 +3,9 @@
 Latency percentiles say *how much* time a request spent; they never say
 *where*.  This module stamps monotonic-clock spans at every state transition
 a request goes through — submit, admit/reject, queued, batch pick,
-pad-and-stack, resolve+compile, device compute (with per-squaring-iteration
-slices for closures), split-results, done/expired/failed — into a
-``FlightRecorder``: a fixed-capacity ring buffer of Chrome trace events.
+pad-and-stack, resolve+compile, device compute, split-results,
+done/expired/failed — into a ``FlightRecorder``: a fixed-capacity ring
+buffer of Chrome trace events.
 
 Why a ring-buffer flight recorder and not a log: the serving loop must never
 block on, allocate unboundedly for, or fsync its own telemetry.  A ring of
@@ -27,43 +27,64 @@ The export format is Chrome trace-event JSON (``export()`` →
     thread's track: ``pad_and_stack``, ``resolve_compile`` (args say cache
     hit or miss), ``device_compute`` (args carry backend, schedule, padded
     batch, H2D bytes, measured iterations), ``split_results``.  Together
-    these are the host/device time breakdown per batch;
-  * closure squaring iterations — the fixpoint runs on device inside one
-    ``lax.while_loop`` with **no host round-trip** (that is the point of
-    it), so per-iteration boundaries are not host-observable.  The tracer
-    apportions the measured device window evenly across the batch's
-    measured max iteration count into ``squaring_iter k`` child slices,
-    marked ``"apportioned": true`` in args — the shape of the fixpoint is
-    visible in the trace without paying a host sync per iteration;
+    these are the host/device time breakdown per batch.  Inside them:
+    ``batch_dispatch`` (the compiled call returning, operand staging
+    included) and ``batch_wait`` (``block_until_ready``) partition
+    ``device_compute``; ``batch_d2h`` (the result's copy to the host)
+    opens ``split_results``;
+  * per-tick arena phases — ``arena_tick`` (launch + sweep) partitioned by
+    ``arena_launch`` (the chunk dispatch), ``arena_wait`` (the host
+    blocking on the tick's flags) and, on ticks that evict,
+    ``arena_readout`` (reading every evicted slot back); then
+    ``arena_finish`` (validation, results, futures) after the tick, and
+    one ``arena_admit`` per admission (host pad + admit dispatch);
+  * runtime spans — ``loop_wait`` (the serving loop blocked for want of
+    work), ``compile`` (one per executable-cache miss, with the key and
+    seconds) and ``gc_pause`` (one per garbage collection, with the
+    generation and the objects collected, while ``watch_gc`` is on);
   * instants (``ph`` 'i') for admission rejections and batch failures.
+
+A child span comes before its parent in the emitted list, so a reader that
+takes the first span of largest overlap names the innermost phase.
 
 Timestamps come from the engine's injected clock (microseconds), so
 synthetic-clock tests produce exact, deterministic traces.
 
 Cost discipline (benchmarks/serve_bench.py asserts the steady-state
 overhead stays under its budget): the whole per-batch event set — batch
-phases, iteration slices, every member request's pick + completion — is
-built locally and pushed in ONE ``batch_complete`` call (one lock, one
-deque extend), and ``enabled=False`` turns every hook into an attribute
-check + return.
+phases, every member request's pick + completion — is built locally and
+pushed in ONE ``batch_complete`` call (one lock, one deque extend); an
+arena tick, its readout and its finished requests likewise ride one
+``arena_tick`` call, and an admission one ``arena_admit`` call.
+``enabled=False`` turns every hook into an attribute check + return.
 """
 from __future__ import annotations
 
 import collections
+import gc
 import threading
 import time
 from typing import Optional, Sequence
 
-__all__ = ["FlightRecorder", "DEFAULT_TRACE_CAPACITY",
-           "MAX_ITERATION_SLICES"]
+__all__ = ["FlightRecorder", "DEFAULT_TRACE_CAPACITY"]
 
-DEFAULT_TRACE_CAPACITY = 65536
-# per-batch cap on apportioned squaring_iter slices: a 1024-node
-# Bellman-Ford bucket measures up to 1023 relaxations; tracing them all
-# would let one batch evict half the ring
-MAX_ITERATION_SLICES = 32
+DEFAULT_TRACE_CAPACITY = 131072
 
 _PID = 1  # one engine process per recorder
+
+
+def _span(name: str, cat: str, tid: int, t0_s: float, t1_s: float,
+          args: Optional[dict] = None) -> dict:
+  """One complete (``X``) event from ``t0_s`` to ``t1_s`` (clock seconds).
+  The length is taken between the scaled edges, so ``ts + dur`` is the
+  scaled ``t1_s`` itself: a child and its parent that share an edge end
+  (or start) on the same float, and overlap a gap by the same amount."""
+  ts, te = t0_s * 1e6, t1_s * 1e6
+  ev = {"ph": "X", "cat": cat, "name": name, "pid": _PID, "tid": tid,
+        "ts": ts, "dur": max(0.0, te - ts)}
+  if args is not None:
+    ev["args"] = args
+  return ev
 
 
 class FlightRecorder:
@@ -71,9 +92,11 @@ class FlightRecorder:
 
   Hooks are grouped by call site: ``request_begin`` (submit),
   ``request_rejected`` (admission), ``batch_complete`` (the whole per-batch
-  event set in one emission), ``request_picked`` / ``request_end`` (the
-  expire/fail paths, where requests terminate outside a completed batch),
-  ``instant``.  Every hook is a no-op when ``enabled`` is False; callers
+  event set in one emission), ``arena_admit`` / ``arena_tick`` (one
+  emission per admission and per tick), ``request_picked`` /
+  ``request_end`` (the expire/fail paths, where requests terminate outside
+  a completed batch or tick), ``span`` (loop waits, compiles),
+  ``instant``; ``watch_gc`` hooks garbage collections in.  Every hook is a no-op when ``enabled`` is False; callers
   with non-trivial args construction should still guard with
   ``if recorder.enabled:`` to keep the disabled path free."""
 
@@ -87,8 +110,17 @@ class FlightRecorder:
     self._lock = threading.Lock()
     self._events: collections.deque = collections.deque(maxlen=self.capacity)
     self._recorded = 0
+    # gc_pause events wait here until the ring's lock is free: a collection
+    # can start in a thread that holds it (see _on_gc)
+    self._gc_pending: collections.deque = collections.deque()
+    self._gc_t0: Optional[float] = None
+    self._gc_hook = self._on_gc
 
   # -- clock -------------------------------------------------------------------
+
+  def now(self) -> float:
+    """The recorder's clock, in seconds."""
+    return self._clock()
 
   def _ts(self, t_s: Optional[float] = None) -> float:
     """Trace timestamp in microseconds (Chrome trace's unit)."""
@@ -104,6 +136,56 @@ class FlightRecorder:
     with self._lock:
       self._events.extend(events)
       self._recorded += len(events)
+      self._drain_gc_locked()
+
+  def _drain_gc_locked(self) -> None:
+    """Move pending ``gc_pause`` events into the ring.  Lock held."""
+    while self._gc_pending:
+      self._events.append(self._gc_pending.popleft())
+      self._recorded += 1
+
+  def span(self, name: str, *, cat: str, t0_s: float, t1_s: float,
+           args: Optional[dict] = None) -> None:
+    """One complete event on the calling thread's track: ``loop_wait``
+    (the serving loop blocked on an empty queue), ``compile`` (one
+    executable-cache miss)."""
+    if not self.enabled:
+      return
+    self._emit((_span(name, cat, self._tid(), t0_s, t1_s, args),))
+
+  # -- garbage collections -----------------------------------------------------
+
+  def watch_gc(self) -> None:
+    """Record a ``gc_pause`` span per garbage collection (idempotent)."""
+    if self.enabled and self._gc_hook not in gc.callbacks:
+      gc.callbacks.append(self._gc_hook)
+
+  def unwatch_gc(self) -> None:
+    if self._gc_hook in gc.callbacks:
+      gc.callbacks.remove(self._gc_hook)
+
+  def _on_gc(self, phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook.  The collection runs in whichever thread
+    allocated, possibly one inside ``_emit`` holding the ring's lock, so
+    the event is queued lock-free and moved into the ring only if the lock
+    is free now; otherwise the next emission or read moves it.  No event
+    is lost and no thread waits."""
+    if phase == "start":
+      self._gc_t0 = self._clock()
+      return
+    t0 = self._gc_t0
+    if t0 is None:  # hooked in mid-collection
+      return
+    self._gc_t0 = None
+    self._gc_pending.append(_span(
+        "gc_pause", "runtime", self._tid(), t0, self._clock(),
+        {"generation": info.get("generation"),
+         "collected": info.get("collected")}))
+    if self._lock.acquire(blocking=False):
+      try:
+        self._drain_gc_locked()
+      finally:
+        self._lock.release()
 
   # -- request lifecycle (nestable async, one id per request) ------------------
 
@@ -151,36 +233,70 @@ class FlightRecorder:
   # -- arena slot lifecycle (admit → tick×k → evict) ---------------------------
 
   def arena_admit(self, rid: int, *, slot: int, bucket: str,
+                  t0_s: Optional[float] = None,
                   t_s: Optional[float] = None) -> None:
     """The request left the queue INTO an arena slot: its ``queued`` slice
-    closes and its ``execute`` slice opens, carrying the slot index.  The
-    slice stays open across every tick the request resides (``arena_tick``
-    X-events land inside it) until ``request_end`` closes it at eviction —
-    together the admit → tick×k → evict span of one slot residency."""
+    closes and its ``execute`` slice opens at ``t_s``, carrying the slot
+    index.  The slice stays open across every tick the request resides
+    (``arena_tick`` X-events land inside it) until the tick that evicts it
+    closes it — together the admit → tick×k → evict span of one slot
+    residency.  With ``t0_s`` an ``arena_admit`` span [t0_s, t_s] (host
+    pad + admit dispatch) rides the same emission."""
     if not self.enabled:
       return
     ts = self._ts(t_s)
     tid = self._tid()
-    self._emit((
-        {"ph": "e", "cat": "request", "id": rid, "name": "queued",
-         "pid": _PID, "tid": tid, "ts": ts},
-        {"ph": "b", "cat": "request", "id": rid, "name": "execute",
-         "pid": _PID, "tid": tid, "ts": ts,
-         "args": {"bucket": bucket, "slot": slot}}))
+    events = []
+    if t0_s is not None:
+      events.append(_span("arena_admit", "arena", tid, t0_s, ts * 1e-6,
+                          {"bucket": bucket, "slot": slot}))
+    events.append({"ph": "e", "cat": "request", "id": rid, "name": "queued",
+                   "pid": _PID, "tid": tid, "ts": ts})
+    events.append({"ph": "b", "cat": "request", "id": rid, "name": "execute",
+                   "pid": _PID, "tid": tid, "ts": ts,
+                   "args": {"bucket": bucket, "slot": slot}})
+    self._emit(events)
 
   def arena_tick(self, bucket: str, *, live: int, evicted: int, g: int,
-                 t0_s: float, t1_s: float) -> None:
-    """One arena tick (≤ g fused iterations over every live slot): a
-    complete event on the serving thread's track, with occupancy and the
-    sweep's eviction count in args."""
+                 t0_s: float, t1_s: float,
+                 launched_s: Optional[float] = None,
+                 flags_s: Optional[float] = None,
+                 finish: Optional[tuple] = None, done=()) -> None:
+    """One arena tick (≤ g fused iterations over every live slot) in one
+    emission: the ``arena_tick`` span [t0_s, t1_s] with occupancy and the
+    sweep's eviction count in args, preceded by its phases when
+    ``launched_s`` is given — ``arena_launch`` [t0_s, launched_s],
+    ``arena_wait`` [launched_s, flags_s] and ``arena_readout``
+    [flags_s, t1_s] on a tick that evicts; ``arena_wait`` runs to t1_s on
+    one that does not.  ``done`` holds (request id, slot, iterations,
+    t_s) per answered eviction, each closing its ``execute`` slice;
+    ``finish`` is the (start, end) of turning evictions into results
+    (``arena_finish``)."""
     if not self.enabled:
       return
-    self._emit((
-        {"ph": "X", "cat": "arena", "name": "arena_tick", "pid": _PID,
-         "tid": self._tid(), "ts": t0_s * 1e6,
-         "dur": max(0.0, (t1_s - t0_s) * 1e6),
-         "args": {"bucket": bucket, "live": live, "evicted": evicted,
-                  "g": g}},))
+    tid = self._tid()
+    events = []
+    if launched_s is not None:
+      readout = evicted > 0 and flags_s is not None
+      events.append(_span("arena_launch", "arena", tid, t0_s, launched_s))
+      events.append(_span("arena_wait", "arena", tid, launched_s,
+                          flags_s if readout else t1_s))
+      if readout:
+        events.append(_span("arena_readout", "arena", tid, flags_s, t1_s,
+                            {"evicted": evicted}))
+    events.append(_span("arena_tick", "arena", tid, t0_s, t1_s,
+                        {"bucket": bucket, "live": live, "evicted": evicted,
+                         "g": g}))
+    for rid, slot, iterations, t_s in done:
+      events.append({"ph": "e", "cat": "request", "id": rid,
+                     "name": "execute", "pid": _PID, "tid": tid,
+                     "ts": t_s * 1e6,
+                     "args": {"outcome": "done", "slot": slot,
+                              "iterations": iterations}})
+    if finish is not None:
+      events.append(_span("arena_finish", "arena", tid, finish[0], finish[1],
+                          {"bucket": bucket, "evicted": evicted}))
+    self._emit(events)
 
   def request_rejected(self, rid: int, reason: str, *, kind: str, op: str,
                        tenant: str, t_s: Optional[float] = None) -> None:
@@ -202,14 +318,19 @@ class FlightRecorder:
                      batch: int, padded: int, h2d_bytes: int,
                      cache_hit: bool, request_ids: Sequence[int],
                      arrivals_s: Sequence[float],
-                     iterations=None, emit_pick: bool = True) -> None:
+                     iterations=None, emit_pick: bool = True,
+                     dispatched_s: Optional[float] = None,
+                     fetched_s: Optional[float] = None) -> None:
     """Emit one completed batch's whole event set in a single lock
     acquisition: the four phase spans (pad_and_stack / resolve_compile /
-    device_compute / split_results), the apportioned squaring-iteration
-    slices for closures, and every member request's queued→execute
-    transition (at the pick instant) and ``execute`` end (outcome done,
-    with its latency).  This is the serving loop's only steady-state trace
-    call, so its cost IS the tracing overhead the bench budgets.
+    device_compute / split_results), their children when stamped —
+    ``batch_dispatch`` [executed_s, dispatched_s] and ``batch_wait``
+    [dispatched_s, device_s] inside ``device_compute``, ``batch_d2h``
+    [device_s, fetched_s] inside ``split_results``, each before its
+    parent — and every member request's queued→execute transition (at the
+    pick instant) and ``execute`` end (outcome done, with its latency).
+    This is the serving loop's only steady-state trace call on the batch
+    path, so its cost IS the tracing overhead the bench budgets.
 
     ``emit_pick=False`` skips the per-request queued→execute transition:
     retried/bisected sub-batches already closed ``queued`` and opened a
@@ -220,43 +341,30 @@ class FlightRecorder:
       return
     tid = self._tid()
     ts_sched = scheduled_s * 1e6
-    ts_exec = executed_s * 1e6
-    ts_dev = device_s * 1e6
     ts_done = completed_s * 1e6
     dev_args = {"bucket": label, "padded": padded, "backend": backend,
                 "schedule": schedule, "h2d_bytes": h2d_bytes}
-    events = [
-        {"ph": "X", "cat": "batch", "name": "pad_and_stack", "pid": _PID,
-         "tid": tid, "ts": ts_sched,
-         "dur": max(0.0, (stacked_s - scheduled_s) * 1e6),
-         "args": {"bucket": label, "batch": batch, "padded": padded,
-                  "h2d_bytes": h2d_bytes}},
-        {"ph": "X", "cat": "batch", "name": "resolve_compile", "pid": _PID,
-         "tid": tid, "ts": stacked_s * 1e6,
-         "dur": max(0.0, (executed_s - stacked_s) * 1e6),
-         "args": {"bucket": label, "cache": "hit" if cache_hit else "miss",
-                  "backend": backend, "schedule": schedule}},
-        {"ph": "X", "cat": "batch", "name": "device_compute", "pid": _PID,
-         "tid": tid, "ts": ts_exec, "dur": max(0.0, ts_dev - ts_exec),
-         "args": dev_args},
-        {"ph": "X", "cat": "batch", "name": "split_results", "pid": _PID,
-         "tid": tid, "ts": ts_dev, "dur": max(0.0, ts_done - ts_dev),
-         "args": {"bucket": label}},
-    ]
     if iterations is not None and len(iterations):
-      its = [int(i) for i in iterations]
-      dev_args["iterations"] = its
-      max_it = max(its)
-      if max_it >= 1 and ts_dev > ts_exec:
-        # see module docstring: apportioned slices, the fixpoint itself is
-        # one on-device while_loop with no host-observable step boundary
-        n = min(max_it, MAX_ITERATION_SLICES)
-        dur = (ts_dev - ts_exec) / n
-        events.extend(
-            {"ph": "X", "cat": "batch", "name": f"squaring_iter {i}",
-             "pid": _PID, "tid": tid, "ts": ts_exec + i * dur, "dur": dur,
-             "args": {"apportioned": True, "iterations": max_it}}
-            for i in range(n))
+      dev_args["iterations"] = [int(i) for i in iterations]
+    events = [
+        _span("pad_and_stack", "batch", tid, scheduled_s, stacked_s,
+              {"bucket": label, "batch": batch, "padded": padded,
+               "h2d_bytes": h2d_bytes}),
+        _span("resolve_compile", "batch", tid, stacked_s, executed_s,
+              {"bucket": label, "cache": "hit" if cache_hit else "miss",
+               "backend": backend, "schedule": schedule}),
+    ]
+    if dispatched_s is not None:
+      events.append(_span("batch_dispatch", "batch", tid, executed_s,
+                          dispatched_s))
+      events.append(_span("batch_wait", "batch", tid, dispatched_s,
+                          device_s))
+    events.append(_span("device_compute", "batch", tid, executed_s, device_s,
+                        dev_args))
+    if fetched_s is not None:
+      events.append(_span("batch_d2h", "batch", tid, device_s, fetched_s))
+    events.append(_span("split_results", "batch", tid, device_s, completed_s,
+                        {"bucket": label}))
     for rid, arrival_s in zip(request_ids, arrivals_s):
       if emit_pick:
         events.append({"ph": "e", "cat": "request", "id": rid,
@@ -337,10 +445,12 @@ class FlightRecorder:
   def events(self) -> list:
     """Snapshot of the live ring (oldest first)."""
     with self._lock:
+      self._drain_gc_locked()
       return list(self._events)
 
   def stats(self) -> dict:
     with self._lock:
+      self._drain_gc_locked()
       live = len(self._events)
       recorded = self._recorded
     return {"enabled": self.enabled, "capacity": self.capacity,
@@ -349,6 +459,7 @@ class FlightRecorder:
 
   def clear(self) -> None:
     with self._lock:
+      self._gc_pending.clear()
       self._events.clear()
       self._recorded = 0
 

@@ -150,6 +150,64 @@ def test_arena_trace_slot_lifecycle():
   assert ends[-1]["args"]["iterations"] == fut.result().extras["iterations"]
 
 
+class _StepClock:
+  """A clock that advances 1 µs at every reading: every span the engine
+  stamps has a length, and equal edges mean one shared reading."""
+
+  def __init__(self):
+    self.t = 0.0
+
+  def __call__(self):
+    self.t += 1e-6
+    return self.t
+
+
+def test_arena_tick_phases_admit_spans_and_compiles():
+  """On the engine: each arena_tick is tiled exactly by its launch, wait
+  and (on an evicting tick) readout children, emitted just before it;
+  arena_finish follows every evicting tick and only those; one
+  arena_admit span per admission; one compile span per cache miss."""
+  eng = MMOEngine(backend="xla", mode="arena", arena_capacity=2, arena_g=2,
+                  clock=_StepClock())
+  futs = [eng.submit(apsp_request(_line(10, s), algorithm="bellman_ford"))
+          for s in (4, 5, 6)]
+  eng.run_until_idle()
+  for f in futs:
+    f.result()
+  xs = [e for e in eng.tracer.events() if e["ph"] == "X"]
+  ticks = [i for i, e in enumerate(xs) if e["name"] == "arena_tick"]
+  assert len(ticks) >= 3
+  evicting = 0
+  for i in ticks:
+    tick = xs[i]
+    evicted = tick["args"]["evicted"]
+    kids = ["arena_launch", "arena_wait"] + (["arena_readout"] if evicted
+                                             else [])
+    parts = xs[i - len(kids):i]
+    assert [p["name"] for p in parts] == kids
+    assert parts[0]["ts"] == tick["ts"]
+    for a, b in zip(parts, parts[1:]):
+      assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1e-6)
+      assert a["dur"] > 0
+    assert parts[-1]["ts"] + parts[-1]["dur"] == pytest.approx(
+        tick["ts"] + tick["dur"], abs=1e-6)
+    after = xs[i + 1] if i + 1 < len(xs) else None
+    finished = after is not None and after["name"] == "arena_finish"
+    assert finished == (evicted > 0)
+    if finished:
+      evicting += 1
+      assert after["ts"] >= tick["ts"] + tick["dur"]
+  assert evicting >= 2  # capacity 2: the third request waits for a slot
+  assert not [e for e in xs if e["name"] == "arena_readout"
+              and e["args"]["evicted"] == 0]
+  admits = [e for e in xs if e["name"] == "arena_admit"]
+  assert len(admits) == 3 and all(e["dur"] > 0 for e in admits)
+  compiles = [e for e in xs if e["name"] == "compile"]
+  assert len(compiles) == eng.cache.misses == 3
+  assert sorted(e["args"]["key"].split("/")[-2] for e in compiles) == [
+      "admit", "read", "tick"]
+
+
 # ---------------------------------------------------------------------------
 # chaos pins — fault injection through the arena path
 # ---------------------------------------------------------------------------

@@ -20,8 +20,8 @@ from repro.serve_mmo.exposition import (HISTOGRAM_BOUNDS_S, LogHistogram,
                                         escape_label_value, render_prometheus)
 from repro.serve_mmo.httpd import PROMETHEUS_CONTENT_TYPE, ObservabilityServer
 from repro.serve_mmo.metrics import RollingWindow, ServeMetrics, bucket_label
-from repro.serve_mmo.observability import (MAX_ITERATION_SLICES,
-                                           FlightRecorder)
+from repro.serve_mmo.cache import ExecutableCache
+from repro.serve_mmo.observability import FlightRecorder
 from repro.serve_mmo.scheduler import BucketKey, request_bucket
 
 from conftest import FakeClock
@@ -126,7 +126,7 @@ def test_lifecycle_timestamps_come_from_injected_clock():
   assert evs[-1]["args"]["outcome"] == "done"
 
 
-def test_batch_complete_emits_phases_requests_and_iteration_slices():
+def test_batch_complete_emits_phases_requests_and_iterations():
   rec = FlightRecorder(clock=FakeClock())
   rec.request_begin(1, kind="closure", op="minplus", tenant="t", t_s=0.0)
   rec.request_begin(2, kind="closure", op="minplus", tenant="t", t_s=0.1)
@@ -138,8 +138,7 @@ def test_batch_complete_emits_phases_requests_and_iteration_slices():
                      arrivals_s=[0.0, 0.1], iterations=[3, 5])
   evs = rec.events()
   _assert_balanced(evs)
-  phases = {ev["name"]: ev for ev in evs
-            if ev["ph"] == "X" and not ev["name"].startswith("squaring")}
+  phases = {ev["name"]: ev for ev in evs if ev["ph"] == "X"}
   assert set(phases) == {"pad_and_stack", "resolve_compile",
                          "device_compute", "split_results"}
   assert phases["pad_and_stack"]["ts"] == pytest.approx(1.0e6)
@@ -148,13 +147,6 @@ def test_batch_complete_emits_phases_requests_and_iteration_slices():
   assert phases["device_compute"]["dur"] == pytest.approx(0.4e6)
   assert phases["device_compute"]["args"]["iterations"] == [3, 5]
   assert phases["split_results"]["dur"] == pytest.approx(0.1e6)
-  # apportioned squaring slices: max measured iterations, tiling exactly the
-  # device window, explicitly marked as apportioned
-  slices = [ev for ev in evs if ev["name"].startswith("squaring_iter")]
-  assert len(slices) == 5
-  assert all(ev["args"]["apportioned"] is True for ev in slices)
-  assert slices[0]["ts"] == pytest.approx(1.3e6)
-  assert sum(ev["dur"] for ev in slices) == pytest.approx(0.4e6)
   # per-request completion args carry the measured latency
   done = [ev for ev in evs if ev.get("cat") == "request"
           and ev["ph"] == "e" and ev["name"] == "execute"]
@@ -162,18 +154,209 @@ def test_batch_complete_emits_phases_requests_and_iteration_slices():
       {1: pytest.approx(1800.0), 2: pytest.approx(1700.0)}
 
 
-def test_iteration_slices_are_capped():
-  """A 1024-node Bellman-Ford batch measures ~1023 relaxations; tracing one
-  slice per relaxation would evict half the ring per batch."""
+def _parent_and_children(evs, parent, children):
+  """The first ``parent`` X-span and the named X-spans emitted before it,
+  in emission order."""
+  xs = [ev for ev in evs if ev["ph"] == "X"]
+  i = next(k for k, ev in enumerate(xs) if ev["name"] == parent)
+  kids = [ev for ev in xs[:i] if ev["name"] in children]
+  return xs[i], kids
+
+
+def test_batch_children_partition_their_parents():
+  """batch_dispatch + batch_wait tile device_compute exactly; batch_d2h
+  opens split_results; each child comes before its parent."""
   rec = FlightRecorder(clock=FakeClock())
-  rec.batch_complete(label="b", scheduled_s=0.0, stacked_s=0.0,
-                     executed_s=0.0, device_s=1.0, completed_s=1.0,
+  rec.batch_complete(label="b", scheduled_s=1.0, stacked_s=1.25,
+                     executed_s=1.5, device_s=2.5, completed_s=3.0,
+                     backend="xla", schedule="local", batch=1, padded=1,
+                     h2d_bytes=0, cache_hit=True, request_ids=[1],
+                     arrivals_s=[0.0], dispatched_s=1.75, fetched_s=2.75)
+  evs = rec.events()
+  dev, kids = _parent_and_children(evs, "device_compute",
+                                   ("batch_dispatch", "batch_wait"))
+  assert [k["name"] for k in kids] == ["batch_dispatch", "batch_wait"]
+  assert kids[0]["ts"] == dev["ts"] == 1.5e6
+  assert kids[0]["ts"] + kids[0]["dur"] == kids[1]["ts"] == 1.75e6
+  assert kids[1]["ts"] + kids[1]["dur"] == dev["ts"] + dev["dur"] == 2.5e6
+  split, kids = _parent_and_children(evs, "split_results", ("batch_d2h",))
+  assert kids[0]["ts"] == split["ts"] == 2.5e6
+  assert kids[0]["dur"] == pytest.approx(0.25e6)
+  # without the stamps the batch reads as before: four phases, no children
+  rec.clear()
+  rec.batch_complete(label="b", scheduled_s=1.0, stacked_s=1.25,
+                     executed_s=1.5, device_s=2.5, completed_s=3.0,
                      backend="xla", schedule="local", batch=1, padded=1,
                      h2d_bytes=0, cache_hit=True, request_ids=[],
-                     arrivals_s=[], iterations=[1000])
-  slices = [ev for ev in rec.events()
-            if ev["name"].startswith("squaring_iter")]
-  assert len(slices) == MAX_ITERATION_SLICES
+                     arrivals_s=[])
+  assert [ev["name"] for ev in rec.events()] == [
+      "pad_and_stack", "resolve_compile", "device_compute", "split_results"]
+
+
+def test_children_share_their_parents_edges_to_the_last_bit():
+  """ts + dur of a child equals its parent's wherever they share an end,
+  so a gap inside both overlaps them equally and the first of the two in
+  the emitted list, the child, names it (these edges round apart when the
+  length is scaled from the unscaled difference)."""
+  rec = FlightRecorder(clock=FakeClock())
+  t, u = 45093.332211, 4028.40832
+  rec.batch_complete(label="b", scheduled_s=t, stacked_s=t + 0.07,
+                     executed_s=t + 0.071, device_s=t + 1.5,
+                     completed_s=t + 1.53, backend="xla", schedule="local",
+                     batch=1, padded=1, h2d_bytes=0, cache_hit=True,
+                     request_ids=[], arrivals_s=[], dispatched_s=t + 0.08,
+                     fetched_s=t + 1.52)
+  rec.arena_tick("a", live=1, evicted=0, g=4, t0_s=u + 2.0, t1_s=u + 2.1,
+                 launched_s=u + 2.001, flags_s=u + 2.1)
+  end = {ev["name"]: ev["ts"] + ev["dur"] for ev in rec.events()}
+  assert end["batch_wait"] == end["device_compute"]
+  assert end["arena_wait"] == end["arena_tick"]
+
+
+def test_arena_tick_phases_partition_the_tick():
+  """arena_launch / arena_wait / arena_readout tile arena_tick exactly and
+  precede it; readout and arena_finish appear only on a tick that evicts,
+  and the tick span keeps its extent [t0, t1]."""
+  rec = FlightRecorder(clock=FakeClock())
+  rec.arena_tick("a", live=2, evicted=1, g=4, t0_s=1.0, t1_s=2.0,
+                 launched_s=1.25, flags_s=1.5, finish=(2.0, 2.5),
+                 done=[(7, 0, 3, 2.25)])
+  evs = rec.events()
+  tick, kids = _parent_and_children(
+      evs, "arena_tick", ("arena_launch", "arena_wait", "arena_readout"))
+  assert [k["name"] for k in kids] == ["arena_launch", "arena_wait",
+                                       "arena_readout"]
+  assert (tick["ts"], tick["dur"]) == (1.0e6, 1.0e6)
+  edges = [kids[0]["ts"]] + [k["ts"] + k["dur"] for k in kids]
+  assert edges == [1.0e6, 1.25e6, 1.5e6, 2.0e6]
+  assert [k["ts"] for k in kids[1:]] == edges[1:-1]
+  end = next(ev for ev in evs if ev["ph"] == "e")
+  assert end["id"] == 7 and end["ts"] == 2.25e6
+  assert end["args"] == {"outcome": "done", "slot": 0, "iterations": 3}
+  finish = evs[-1]
+  assert finish["name"] == "arena_finish"
+  assert (finish["ts"], finish["dur"]) == (2.0e6, 0.5e6)
+
+  rec.clear()
+  rec.arena_tick("a", live=2, evicted=0, g=4, t0_s=1.0, t1_s=2.0,
+                 launched_s=1.25, flags_s=1.5)
+  evs = rec.events()
+  assert [ev["name"] for ev in evs] == ["arena_launch", "arena_wait",
+                                        "arena_tick"]
+  # nothing read out: the wait runs to the end of the tick
+  assert evs[1]["ts"] + evs[1]["dur"] == 2.0e6
+
+
+def test_arena_admit_is_a_span_beside_the_slot_transition():
+  rec = FlightRecorder(clock=FakeClock())
+  rec.request_begin(3, kind="closure", op="orand", tenant="t", t_s=0.5)
+  rec.arena_admit(3, slot=1, bucket="a", t0_s=1.0, t_s=1.5)
+  evs = rec.events()
+  _assert_balanced(evs + [{"cat": "request", "ph": "e", "id": 3,
+                           "name": "execute", "ts": 2.0e6}])
+  admit = [ev for ev in evs if ev["name"] == "arena_admit"]
+  assert len(admit) == 1 and admit[0]["ph"] == "X"
+  assert (admit[0]["ts"], admit[0]["dur"]) == (1.0e6, 0.5e6)
+  # the execute slice opens where the admission span ends
+  begin = next(ev for ev in evs if ev["ph"] == "b" and ev["name"] == "execute")
+  assert begin["ts"] == 1.5e6 and begin["args"] == {"bucket": "a", "slot": 1}
+
+
+def test_compile_span_once_per_cache_miss_never_on_a_hit():
+  import jax.numpy as jnp
+  rec = FlightRecorder()
+  cache = ExecutableCache(recorder=rec)
+  arg = np.zeros((4,), np.float32)
+  for _ in range(3):
+    cache.get_or_compile("double", lambda: (lambda x: 2 * x), (arg,),
+                         label="double/4")
+  cache.get_or_compile(("neg", 4), lambda: jnp.negative, (arg,))
+  spans = [ev for ev in rec.events() if ev["name"] == "compile"]
+  assert cache.misses == 2 and len(spans) == 2
+  assert [ev["args"]["key"] for ev in spans] == ["double/4", "('neg', 4)"]
+  assert all(ev["ph"] == "X" and ev["args"]["seconds"] > 0 for ev in spans)
+  # a disabled recorder, or none, leaves no span
+  quiet = FlightRecorder(enabled=False)
+  ExecutableCache(recorder=quiet).get_or_compile(
+      "double", lambda: (lambda x: 2 * x), (arg,))
+  assert quiet.events() == []
+
+
+def test_loop_wait_only_when_the_loop_blocked():
+  """Work queued before the loop starts is served without a wait; a
+  request arriving while the loop sleeps ends one loop_wait span."""
+  engine = MMOEngine(backend="xla", max_batch=4)
+  futs = [engine.submit(_mmo_req()) for _ in range(2)]
+  engine.start()
+  for f in futs:
+    f.result(timeout=60)
+  engine.stop()
+  assert not [ev for ev in engine.tracer.events()
+              if ev["name"] == "loop_wait"]
+
+  engine.tracer.clear()
+  engine.start()
+  import time
+  time.sleep(0.2)  # the loop finds the queue empty and blocks
+  t_submit = time.perf_counter()
+  engine.submit(_mmo_req()).result(timeout=60)
+  engine.stop()
+  evs = engine.tracer.events()
+  waits = [ev for ev in evs if ev["name"] == "loop_wait"]
+  assert len(waits) == 1
+  wait_end = waits[0]["ts"] + waits[0]["dur"]
+  assert wait_end >= t_submit * 1e6 and waits[0]["dur"] > 0
+  stack = next(ev for ev in evs if ev["name"] == "pad_and_stack")
+  assert wait_end <= stack["ts"]
+
+
+def test_gc_pause_spans_while_the_engine_runs():
+  import gc
+  engine = MMOEngine(backend="xla")
+  engine.start()
+  assert engine.tracer._gc_hook in gc.callbacks
+  gc.collect()
+  engine.stop()
+  assert engine.tracer._gc_hook not in gc.callbacks
+  spans = [ev for ev in engine.tracer.events() if ev["name"] == "gc_pause"]
+  assert spans and spans[-1]["ph"] == "X"
+  assert spans[-1]["args"]["generation"] == 2
+  assert spans[-1]["args"]["collected"] >= 0
+  before = len(engine.tracer.events())
+  gc.collect()  # unhooked: nothing more is recorded
+  assert len(engine.tracer.events()) == before
+  # a recorder that is off never hooks in
+  off = MMOEngine(backend="xla", trace=False)
+  off.start()
+  assert off.tracer._gc_hook not in gc.callbacks
+  off.stop()
+
+
+def test_gc_during_an_emission_neither_blocks_nor_loses_the_event():
+  """A collection can start in a thread that holds the ring's lock (inside
+  an emission): gc.collect() must return, and its span reach the ring once
+  the lock is free."""
+  import gc
+  rec = FlightRecorder()
+  rec.watch_gc()
+  finished = threading.Event()
+
+  def collect_under_the_lock():
+    with rec._lock:
+      gc.collect()
+    finished.set()
+
+  try:
+    t = threading.Thread(target=collect_under_the_lock, daemon=True)
+    t.start()
+    t.join(timeout=30)
+    assert finished.is_set(), "gc.collect() blocked on the recorder's lock"
+  finally:
+    rec.unwatch_gc()
+  st = rec.stats()
+  spans = [ev for ev in rec.events() if ev["name"] == "gc_pause"]
+  assert spans and st["recorded"] == st["live"] >= len(spans)
+  assert st["dropped"] == 0
 
 
 def test_export_is_json_serializable_chrome_trace():
@@ -215,13 +398,12 @@ def test_live_trace_is_balanced_and_loads_as_json(served_engine):
   names = {ev["name"] for ev in evs}
   assert {"pad_and_stack", "resolve_compile", "device_compute",
           "split_results", "queued", "execute"} <= names
-  # the closure batches ran a measured fixpoint → apportioned slices and
-  # measured iteration counts on the device span
+  # the closure batches ran a measured fixpoint → measured iteration counts
+  # on the device span
   closure_devs = [ev for ev in evs if ev["name"] == "device_compute"
                   and "iterations" in ev.get("args", {})]
   assert closure_devs and all(
       min(ev["args"]["iterations"]) >= 1 for ev in closure_devs)
-  assert any(ev["name"].startswith("squaring_iter") for ev in evs)
   # every completed request closed its execute slice with outcome=done
   done = [ev for ev in evs if ev.get("cat") == "request"
           and ev["ph"] == "e" and ev["name"] == "execute"]
