@@ -265,7 +265,7 @@ def mmo(a: Array,
     if block is None:
       block = cfg
 
-  bm = bn = bk = 128
+  bm = bn = bk = None  # unset: the Pallas kernel chooses its geometry
   if block:
     if backend == "pallas":
       if len(block) != 3:

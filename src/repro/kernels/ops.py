@@ -23,10 +23,14 @@ def _on_tpu() -> bool:
 
 
 def semiring_mmo(a: Array, b: Array, c: Optional[Array] = None, *,
-                 op: str = "mma", bm: int = 128, bn: int = 128, bk: int = 128,
+                 op: str = "mma", bm: Optional[int] = None,
+                 bn: Optional[int] = None, bk: Optional[int] = None,
                  interpret: Optional[bool] = None, faithful: bool = False,
                  k_valid: Optional[Array] = None) -> Array:
   """Batched-aware Pallas MMO; vmaps leading batch dims onto the 2-D kernel.
+
+  Block sizes left None are the kernel's own choice
+  (``semiring_mmo.block_geometry``).
 
   ``k_valid`` broadcasts over the batch dims (one live-K scalar per kernel
   instance), so a (R, M, K) batch takes an (R,) vector of per-request K

@@ -17,7 +17,10 @@ consistent (misses counts compile *attempts*, so `misses >= executables`).
 
 With a ``recorder`` (the engine's ``FlightRecorder``) every miss also
 leaves a ``compile`` span — the key's label and the seconds it took — so
-the trace says which program compiled, and when.
+the trace says which program compiled, and when.  Its ``kernels`` arg names
+each Pallas kernel of the program that states its geometry (the VPU
+semiring kernel's DMA block and accumulator strip), so the trace also says
+which kernel layout ran.
 """
 from __future__ import annotations
 
@@ -29,6 +32,25 @@ from typing import Callable, Optional
 import jax
 
 from repro.serve_mmo.observability import FlightRecorder
+
+
+def _kernel_metadata(jaxpr) -> dict:
+  """Kernel name → metadata of every Pallas kernel in ``jaxpr`` (a
+  ``Jaxpr`` or ``ClosedJaxpr``, nested programs included) that carries
+  some."""
+  found, stack = {}, [getattr(jaxpr, "jaxpr", jaxpr)]
+  while stack:
+    for eqn in stack.pop().eqns:
+      if eqn.primitive.name == "pallas_call":
+        if eqn.params.get("metadata"):
+          found[eqn.params["name"]] = dict(eqn.params["metadata"])
+        continue
+      for value in eqn.params.values():
+        for sub in value if isinstance(value, (tuple, list)) else (value,):
+          sub = getattr(sub, "jaxpr", sub)
+          if hasattr(sub, "eqns"):
+            stack.append(sub)
+  return found
 
 
 @dataclasses.dataclass
@@ -88,13 +110,17 @@ class ExecutableCache:
     t0 = time.perf_counter()
     abstract = tuple(
         jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
-    compiled = jax.jit(make_fn()).lower(*abstract).compile()
+    program = jax.jit(make_fn()).trace(*abstract)
+    compiled = program.lower().compile()
     elapsed = time.perf_counter() - t0
     if traced:
+      args = {"key": str(exec_key) if label is None else label,
+              "seconds": elapsed}
+      kernels = _kernel_metadata(program.jaxpr)
+      if kernels:
+        args["kernels"] = kernels
       recorder.span("compile", cat="cache", t0_s=span_t0,
-                    t1_s=recorder.now(),
-                    args={"key": str(exec_key) if label is None else label,
-                          "seconds": elapsed})
+                    t1_s=recorder.now(), args=args)
     with self._lock:
       entry = self._entries.get(exec_key)
       if entry is not None:  # lost the compile race: first insert wins

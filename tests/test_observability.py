@@ -22,7 +22,8 @@ from repro.serve_mmo.httpd import PROMETHEUS_CONTENT_TYPE, ObservabilityServer
 from repro.serve_mmo.metrics import RollingWindow, ServeMetrics, bucket_label
 from repro.serve_mmo.cache import ExecutableCache
 from repro.serve_mmo.observability import FlightRecorder
-from repro.serve_mmo.scheduler import BucketKey, request_bucket
+from repro.serve_mmo.scheduler import (BucketKey, contract_shape,
+                                      request_bucket)
 
 from conftest import FakeClock
 
@@ -280,6 +281,31 @@ def test_compile_span_once_per_cache_miss_never_on_a_hit():
   ExecutableCache(recorder=quiet).get_or_compile(
       "double", lambda: (lambda x: 2 * x), (arg,))
   assert quiet.events() == []
+
+
+def test_compile_span_names_the_vpu_kernel_geometry():
+  """A compile of a VPU-ring Pallas program records the kernel's geometry
+  (the same one ``block_geometry`` picks for the bucket); an MXU ring's
+  program, whose kernel states none, records no ``kernels`` arg."""
+  import importlib
+  sm = importlib.import_module("repro.kernels.semiring_mmo")
+  engine = MMOEngine(backend="pallas", max_batch=1)
+  a = RNG.standard_normal((12, 12)).astype(np.float32)
+  futs = [engine.submit(mmo_request(a, a, op=op)) for op in ("minplus",
+                                                              "mma")]
+  engine.run_until_idle()
+  for f in futs:
+    f.result()
+  spans = {ev["args"]["key"].split("/")[1]: ev["args"]
+           for ev in engine.tracer.events() if ev["name"] == "compile"}
+  assert set(spans) == {"minplus", "mma"}
+  m, k, n = contract_shape(request_bucket(mmo_request(a, a, op="minplus"),
+                                         engine.scheduler.min_bucket))
+  g = sm.block_geometry("minplus", m, k, n)
+  assert spans["minplus"]["kernels"] == {
+      "simd2_minplus": {"block": f"{g.bm}x{g.bn}x{g.bk}",
+                        "strip": str(g.strip)}}
+  assert "kernels" not in spans["mma"]
 
 
 def test_loop_wait_only_when_the_loop_blocked():

@@ -60,10 +60,12 @@ def _pow2_buckets(cap):
 
 @pytest.mark.parametrize("n", (128, 4096))
 @pytest.mark.parametrize("op", ("mma", "addnorm", "minplus", "maxmin",
-                                "orand"))
+                                "orand", "maxplus", "minmul", "maxmul"))
 def test_semiring_mmo_compiles(one_chip, op, n):
   """The batched serving entry (leading request axis + per-request live K)
-  for one ring of each family: MXU, MXU rewrite, VPU min/max, boolean."""
+  for one ring of each family — MXU, MXU rewrite, VPU min/max, boolean —
+  and every path ring of the benchmark: the VPU kernel's wide blocks must
+  fit the scoped VMEM and lower through Mosaic."""
   dtype = jnp.bool_ if op == "orand" else jnp.float32
   a = _spec((2, n, n), dtype, one_chip)
   kv = _spec((2,), jnp.int32, one_chip)
