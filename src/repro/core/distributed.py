@@ -197,7 +197,7 @@ def mmo_dp_batched(a: Array, b: Array, c: Optional[Array] = None, *,
   Each device contracts its own R/P requests locally — zero collectives,
   the vLLM-style scale-out schedule for a bucket batch of *independent*
   problems.  Requires R divisible by the mesh's device count (the engine
-  falls back to 'local' for partial batches).
+  rounds a partial batch up to a multiple of it with inert padding slots).
   """
   if a.shape[0] % mesh.size:
     raise ValueError(f"dp needs the request axis ({a.shape[0]}) divisible by "
@@ -363,9 +363,8 @@ def schedule_fits(schedule: str, m: int, k: int, n: int, mesh: Mesh) -> bool:
   of two, so any pow2 mesh axis ≤ the dim fits)."""
   rows, cols = mesh.shape[mesh.axis_names[0]], mesh.shape[mesh.axis_names[-1]]
   if schedule == "dp":
-    return True  # no problem-axis constraint; the request axis is checked
-    # at batch-build time (the engine falls back to 'local' when the padded
-    # batch doesn't divide over the mesh)
+    return True  # no problem-axis constraint; the engine pads the request
+    # axis to a multiple of the mesh's devices at batch-build time
   if schedule == "kspan":
     return k % cols == 0
   if schedule == "summa":

@@ -234,7 +234,9 @@ def _make_vpu_kernel(sr: sr_mod.Semiring, acc_dtype, has_c: bool,
         rows = pl.ds(pl.multiple_of(s * strip, strip), strip)
 
         def chunk(c, acc):
-          k0 = pl.multiple_of(c * ck, ck)
+          # a block of one chunk narrower than a lane tile (k < 128) loads
+          # at a static offset: Mosaic cannot prove a dynamic one aligned
+          k0 = 0 if nchunk == 1 else pl.multiple_of(c * ck, ck)
           a_c = a_ref[rows, pl.ds(k0, ck)].astype(acc_dtype)
           for t in range(ck):
             # B rows load one at a time, next to their use: a whole

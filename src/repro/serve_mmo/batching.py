@@ -15,6 +15,11 @@ Three pieces per bucket:
                        live-K / valid-n vector: because the padding is an
                        algebraic no-op, the backends may *skip* dead K work
                        instead of computing it (ragged masked-K execution).
+                       ``inert`` trailing slots hold no request at all: a
+                       live size of 0 and operands that are already the
+                       answer (a closure slot is its own fixpoint), so a
+                       dp shard holding only those leaves its fixpoint at
+                       the first convergence check.
   ``make_batch_fn``  — the pure jax function the executable cache compiles:
                        mmo_batched / batched_*_closure (per-request
                        convergence masks) / addnorm+top-k.
@@ -23,6 +28,7 @@ Three pieces per bucket:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -44,53 +50,79 @@ def _pad2d(x: np.ndarray, rows: int, cols: int,
   return out
 
 
-def _stack_mmo(key: BucketKey, reqs: Sequence[ProblemRequest]):
+@functools.lru_cache(maxsize=64)
+def _filled(shape: tuple, value, dtype: str) -> np.ndarray:
+  """A read-only ``shape`` array of ``value``: one inert slot's operand."""
+  out = np.full(shape, value, np.dtype(dtype))
+  out.flags.writeable = False
+  return out
+
+
+@functools.lru_cache(maxsize=64)
+def _empty_graph(op: str, nb: int, dtype: str) -> np.ndarray:
+  """``nb`` isolated vertices: the closure slot that is its own fixpoint."""
+  out = cl_mod.pad_adjacency(np.zeros((0, 0), np.dtype(dtype)), nb, op=op)
+  out.flags.writeable = False
+  return out
+
+
+def _stack_mmo(key: BucketKey, reqs: Sequence[ProblemRequest], inert: int):
   mb, kb, nb = key.shape
   pa, pb = sr_mod.contraction_pads(key.op)
   boolean = sr_mod.get(key.op).boolean
   if boolean:
     pa = pb = False
   (has_c,) = key.params
-  a = np.stack([_pad2d(r.arrays["a"], mb, kb, pa, pa) for r in reqs])
-  b = np.stack([_pad2d(r.arrays["b"], kb, nb, pb, pb) for r in reqs])
+  a = np.stack([_pad2d(r.arrays["a"], mb, kb, pa, pa) for r in reqs]
+               + [_filled((mb, kb), pa, key.dtypes[0])] * inert)
+  b = np.stack([_pad2d(r.arrays["b"], kb, nb, pb, pb) for r in reqs]
+               + [_filled((kb, nb), pb, key.dtypes[1])] * inert)
   # per-request live-K: lanes beyond a request's true K are contraction pads
   # (⊗(pa, pb) == ⊕-identity), so backends may skip them (ragged masked-K)
-  kv = np.asarray([r.shape[1] for r in reqs], np.int32)
+  kv = np.asarray([r.shape[1] for r in reqs] + [0] * inert, np.int32)
   if not has_c:
     return (a, b, kv)
   ident = False if boolean else sr_mod.get(key.op).oplus_identity
-  c = np.stack([_pad2d(r.arrays["c"], mb, nb, ident, ident) for r in reqs])
+  c = np.stack([_pad2d(r.arrays["c"], mb, nb, ident, ident) for r in reqs]
+               + [_filled((mb, nb), ident, key.dtypes[2])] * inert)
   return (a, b, c, kv)
 
 
-def _stack_closure(key: BucketKey, reqs: Sequence[ProblemRequest]):
+def _stack_closure(key: BucketKey, reqs: Sequence[ProblemRequest],
+                   inert: int):
   (nb,) = key.shape
   adj = np.stack([cl_mod.pad_adjacency(r.arrays["adj"], nb, op=key.op)
-                  for r in reqs])
+                  for r in reqs]
+                 + [_empty_graph(key.op, nb, key.dtypes[0])] * inert)
   # true problem sizes: rows/cols beyond valid[r] are isolated-vertex padding
-  valid = np.asarray([r.shape[0] for r in reqs], np.int32)
+  valid = np.asarray([r.shape[0] for r in reqs] + [0] * inert, np.int32)
   return (adj, valid)
 
 
-def _stack_knn(key: BucketKey, reqs: Sequence[ProblemRequest]):
+def _stack_knn(key: BucketKey, reqs: Sequence[ProblemRequest], inert: int):
   qb, rb, db = key.shape
   # all pads are zeros (query pad rows' outputs are sliced away; padded dims
   # contribute (0-0)²=0 for real rows); ``valid`` carries each request's true
   # corpus size so the compiled program can mask padded rows out of top-k.
-  q = np.stack([_pad2d(r.arrays["queries"], qb, db, 0.0, 0.0) for r in reqs])
-  ref = np.stack([_pad2d(r.arrays["corpus"], rb, db, 0.0, 0.0) for r in reqs])
-  valid = np.asarray([r.arrays["corpus"].shape[0] for r in reqs], np.int32)
+  q = np.stack([_pad2d(r.arrays["queries"], qb, db, 0.0, 0.0) for r in reqs]
+               + [_filled((qb, db), 0.0, key.dtypes[0])] * inert)
+  ref = np.stack([_pad2d(r.arrays["corpus"], rb, db, 0.0, 0.0) for r in reqs]
+                 + [_filled((rb, db), 0.0, key.dtypes[1])] * inert)
+  valid = np.asarray([r.arrays["corpus"].shape[0] for r in reqs]
+                     + [0] * inert, np.int32)
   return (q, ref, valid)
 
 
-def stack_batch(key: BucketKey, reqs: Sequence[ProblemRequest]):
-  """Pad + stack all request operands for one bucket batch."""
+def stack_batch(key: BucketKey, reqs: Sequence[ProblemRequest],
+                inert: int = 0):
+  """Pad + stack all request operands for one bucket batch, then ``inert``
+  slots that hold no request (module docstring)."""
   if key.kind == "mmo":
-    return _stack_mmo(key, reqs)
+    return _stack_mmo(key, reqs, inert)
   if key.kind == "closure":
-    return _stack_closure(key, reqs)
+    return _stack_closure(key, reqs, inert)
   if key.kind == "knn":
-    return _stack_knn(key, reqs)
+    return _stack_knn(key, reqs, inert)
   raise ValueError(f"unknown kind {key.kind!r}")
 
 

@@ -91,6 +91,10 @@ class EngineStats:
   over_cap: int = 0
   # (bucket label, backend, schedule) → batches (arena: ticks) that arm ran
   arms: dict = dataclasses.field(default_factory=dict)
+  # slots of the dp batches served: requests, and the inert padding that
+  # rounds each batch up to a multiple of the mesh's devices
+  dp_live_slots: int = 0
+  dp_inert_slots: int = 0
 
   def percentile(self, q: float) -> float:
     if len(self.latencies_s) == 0:
@@ -301,6 +305,8 @@ class MMOEngine:
     self._expired = 0
     self._over_cap = 0
     self._arms: dict = {}  # (bucket label, backend, schedule) → batches
+    self._dp_live = 0   # request slots of the dp batches served
+    self._dp_inert = 0  # inert padding slots of those batches
     self._next_id = 0
     self._pending: dict[int, MMOFuture] = {}
     self._inflight: set[int] = set()  # popped from the queue, executing now
@@ -437,6 +443,16 @@ class MMOEngine:
     log2(max_batch)+1 executables instead of one per arrival count."""
     return bucket_dim(r, 1)
 
+  def _padded_batch(self, r: int, schedule: str) -> int:
+    """The batch size a placement runs ``r`` requests at: the power-of-two
+    bucket, rounded up to a multiple of the mesh's devices under dp so the
+    request axis always divides over the mesh (1–4 requests on four chips
+    run at 4, 5–8 at 8)."""
+    rb = self._batch_bucket(r)
+    if schedule == "dp":
+      rb = -(-rb // self.mesh.size) * self.mesh.size
+    return rb
+
   @staticmethod
   def _megakernel_serves(key) -> bool:
     """Whether the fused megakernel (and so the arena) can hold this bucket:
@@ -524,19 +540,14 @@ class MMOEngine:
                           schedules=tuple(fits))
     return d.backend if d.backend in fits else "local"
 
-  def resolve_placement(self, key, rb: Optional[int] = None) -> tuple:
+  def resolve_placement(self, key) -> tuple:
     """(backend, block cfg, schedule) — the full per-bucket decision.  The
     backend doubles as each shard's local contraction path when the bucket
-    is routed to the mesh.  With ``rb`` (the padded batch size), dp falls
-    back to 'local' for batches that don't divide over the mesh's devices —
-    a per-(bucket, rb) refinement, deterministic because rb is part of the
-    executable-cache key."""
+    is routed to the mesh.  A dp bucket runs every batch on the mesh: its
+    batch size rounds up to a multiple of the mesh's devices
+    (``_padded_batch``) and the padding slots are inert."""
     backend, block = self.resolve_backend(key)
-    schedule = self.resolve_schedule(key)
-    if (schedule == "dp" and rb is not None
-        and rb % self.mesh.size != 0):
-      schedule = "local"
-    return backend, block, schedule
+    return backend, block, self.resolve_schedule(key)
 
   def _exec_key(self, key, rb: int, backend: str, block: tuple,
                 schedule: str) -> tuple:
@@ -865,8 +876,9 @@ class MMOEngine:
     request in a batch of B therefore costs O(log B) extra launches — it
     keeps landing in ever-smaller failing halves until it fails alone —
     and total attempts are bounded by (retries+1)·(2B−1).  Every sub-batch
-    size is re-bucketed to its own power of two, so bisection launches hit
-    existing executable-cache entries (prewarm compiles every pow2 batch).
+    size is re-bucketed to its own padded size, so bisection launches hit
+    existing executable-cache entries (prewarm compiles every batch size
+    ``_padded_batch`` can give).
 
     Accounting across attempts is once-per-request for final outcomes
     (``on_complete`` / ``on_fail`` / admission / futures), per-attempt for
@@ -935,11 +947,11 @@ class MMOEngine:
     trace exactly); retries stamp their own start."""
     label = bucket_label(key)
     rids = [r.request_id for r in reqs]
-    rb = self._batch_bucket(len(reqs))
-    primary = self.resolve_placement(key, rb)
+    primary = self.resolve_placement(key)
     arm, probe = self.resilience.pick(key, primary,
                                       lambda: self._fallback_arms(key))
     backend, block, schedule = arm
+    rb = self._padded_batch(len(reqs), schedule)
     if self.tracer.enabled and probe:
       self.tracer.instant("breaker_probe", cat="resilience",
                           args={"bucket": label, "backend": backend,
@@ -948,9 +960,11 @@ class MMOEngine:
     attempt_s = self._clock() if start_s is None else start_s
     phase = "stack"
     try:
-      # fill the padded batch slots with copies of the last request — wasted
-      # compute bounded at 2×, in exchange for a bounded executable set
-      stacked = batching.stack_batch(key, reqs + [reqs[-1]] * (rb - len(reqs)))
+      # the padding slots that keep the executable set bounded are inert:
+      # live size 0, operands already the answer, so a closure slot (and a
+      # dp shard holding only padding) leaves its fixpoint at the first
+      # check instead of recomputing a request
+      stacked = batching.stack_batch(key, reqs, inert=rb - len(reqs))
       h2d_bytes = batching.stacked_nbytes(stacked)
       stacked_s = self._clock()
       phase = "compile"
@@ -1016,9 +1030,8 @@ class MMOEngine:
         # run — BEFORE validation/splitting/fulfilling, so a batch that
         # fails later in this attempt still feeds the estimator what the
         # device actually measured.  Live slots only (padded slots are
-        # copies of the last request), and only rids not observed by an
-        # earlier attempt — a re-executed fixpoint measures the same
-        # convergence and must not double-feed the EWMA.
+        # inert), and only rids not observed by an earlier attempt — a re-executed fixpoint measures
+        # the same convergence and must not double-feed the EWMA.
         iters_live = np.asarray(out[1])[:len(reqs)]
         fresh = [i for i, r in enumerate(reqs)
                  if r.request_id not in observed]
@@ -1059,11 +1072,12 @@ class MMOEngine:
                                 "schedule": schedule})
     # live service-latency feedback: the same signal that fills the metrics
     # windows (minus compile time — see executed_s above), normalized per
-    # padded slot.  Keyed by the arm that ACTUALLY executed — which the
-    # breaker may have re-dispatched and resolve_placement may have
-    # downgraded to 'local' for this rb — so a dp cell never averages in
-    # local-path latencies and a fallback arm's cell prices itself.
-    self.estimator.observe_batch(key, backend, schedule, rb,
+    # live request: inert padding slots leave their fixpoint at once (and
+    # under dp run on other chips), so they do not dilute a request's cost.
+    # Keyed by the arm that ACTUALLY executed — which the breaker may have
+    # re-dispatched to 'local' — so a dp cell never averages in local-path
+    # latencies and a fallback arm's cell prices itself.
+    self.estimator.observe_batch(key, backend, schedule, len(reqs),
                                  completed_s - executed_s)
     info = {"start_s": attempt_s, "stacked_s": stacked_s,
             "executed_s": executed_s, "device_s": device_s,
@@ -1072,6 +1086,14 @@ class MMOEngine:
             "cache_hit": cache_hit, "backend": backend,
             "schedule": schedule, "iters_live": iters_live}
     return results, info
+
+  def _chips_live(self, schedule: str, live: int, rb: int) -> int:
+    """Devices holding at least one request of a sharded batch: dp gives
+    each device ``rb / P`` consecutive slots, requests first; the problem-
+    axis schedules split every request over every device."""
+    if schedule != "dp":
+      return self.mesh.size
+    return -(-live // (rb // self.mesh.size))
 
   def _complete_sub(self, key, reqs, results, info, scheduled_s: float,
                     *, emit_pick: bool) -> int:
@@ -1082,6 +1104,10 @@ class MMOEngine:
     time), while the batch phase spans use the attempt's own timestamps."""
     completed_s = info["completed_s"]
     if self.tracer.enabled:
+      schedule, rb = info["schedule"], info["rb"]
+      sharding = None if schedule == "local" else {
+          "schedule": schedule, "rb": rb, "live": len(reqs),
+          "chips_live": self._chips_live(schedule, len(reqs), rb)}
       # one call carries the whole attempt's event set (phase spans and
       # their children, member picks + dones) so the steady-state tracing
       # cost is one lock acquisition per batch, not per request
@@ -1095,11 +1121,15 @@ class MMOEngine:
           request_ids=[r.request_id for r in reqs],
           arrivals_s=[r.arrival_s for r in reqs],
           iterations=info["iters_live"], emit_pick=emit_pick,
-          dispatched_s=info["dispatched_s"], fetched_s=info["fetched_s"])
+          dispatched_s=info["dispatched_s"], fetched_s=info["fetched_s"],
+          sharding=sharding)
     with self._lock:
       self._batches += 1
       arm = (bucket_label(key), info["backend"], info["schedule"])
       self._arms[arm] = self._arms.get(arm, 0) + 1
+      if info["schedule"] == "dp":
+        self._dp_live += len(reqs)
+        self._dp_inert += info["rb"] - len(reqs)
       if (key.kind == "closure" and not self._megakernel_serves(key)
           and (self.mode == "arena" or self.backend == "megakernel")):
         self._over_cap += len(reqs)
@@ -1322,8 +1352,8 @@ class MMOEngine:
     return self.tracer.export()
 
   def prewarm(self, sample_reqs) -> int:
-    """Compile every (bucket, pow2-batch) executable the sample's buckets can
-    produce, without executing anything.  Returns #programs compiled.  After
+    """Compile every (bucket, padded batch) executable the sample's buckets
+    can produce, without executing anything.  Returns #programs compiled.  After
     ``prewarm``, traffic confined to those buckets causes zero recompiles —
     the steady-state guarantee benchmarks/serve_bench.py asserts.
     """
@@ -1342,19 +1372,17 @@ class MMOEngine:
           arena = self._arena_for_locked(key)
         arena.prewarm()
         continue
-      rb = 1
-      while True:
-        backend, block, schedule = self.resolve_placement(key, rb)
+      backend, block, schedule = self.resolve_placement(key)
+      sizes = {self._padded_batch(min(1 << i, max_batch), schedule)
+               for i in range(max_batch.bit_length() + 1)}
+      for rb in sorted(sizes):
         self.cache.get_or_compile(
             self._exec_key(key, rb, backend, block, schedule),
-            lambda s=schedule: batching.make_batch_fn(
+            lambda: batching.make_batch_fn(
                 key, backend=backend, block=block, interpret=self.interpret,
-                mesh=self.mesh, schedule=s),
+                mesh=self.mesh, schedule=schedule),
             batching.abstract_batch(key, rb),
             label=self._exec_label(key, rb, backend, schedule))
-        if rb >= max_batch:
-          break
-        rb = self._batch_bucket(min(2 * rb, max_batch))
     return self.cache.misses - before
 
   # -- background serving loop -----------------------------------------------
@@ -1422,6 +1450,7 @@ class MMOEngine:
       batches = self._batches
       rejected, expired = self._rejected, self._expired
       over_cap, arms = self._over_cap, dict(self._arms)
+      dp_live, dp_inert = self._dp_live, self._dp_inert
     lat = np.asarray([r.latency_s for r in recs], dtype=np.float64)
     return EngineStats(
         completed=len(recs),
@@ -1433,6 +1462,8 @@ class MMOEngine:
         expired=expired,
         over_cap=over_cap,
         arms=arms,
+        dp_live_slots=dp_live,
+        dp_inert_slots=dp_inert,
     )
 
   def reset_stats(self):
@@ -1443,3 +1474,5 @@ class MMOEngine:
       self._expired = 0
       self._over_cap = 0
       self._arms.clear()
+      self._dp_live = 0
+      self._dp_inert = 0

@@ -11,8 +11,8 @@ loop:
 
   * every completed batch contributes one observation — the same service
     latency that lands in the ``ServeMetrics`` rolling windows — normalized
-    to per-request seconds (batch compute scales linearly with occupied
-    slots, so seconds / padded-batch-size is the request's marginal cost),
+    to per-request seconds (seconds / live requests: a batch's padding
+    slots are inert, so they add no fixpoint work of their own),
     keyed by (bucket, backend, schedule) so a bucket re-routed to the mesh
     or to a different backend never inherits stale numbers;
   * closure batches additionally contribute their *measured* convergence
@@ -105,23 +105,25 @@ class ServiceEstimator:
 
   # -- observations (serving-loop side) ---------------------------------------
 
-  def observe_batch(self, key, backend: str, schedule: str, slots: int,
+  def observe_batch(self, key, backend: str, schedule: str, requests: int,
                     seconds: float) -> None:
-    """One completed batch: ``seconds`` of device service over ``slots``
-    padded batch slots (the executable computes every slot, so per-request
-    marginal cost is seconds / slots)."""
-    if slots < 1 or not (seconds >= 0.0 and math.isfinite(seconds)):
+    """One completed batch: ``seconds`` of device service for ``requests``
+    live requests.  The batch's padding slots are inert (they leave their
+    fixpoint at the first check, and under dp run on other chips), so they
+    do not count: per-request cost is seconds / requests."""
+    if requests < 1 or not (seconds >= 0.0 and math.isfinite(seconds)):
       return  # never let a bogus reading poison the estimate
     cell_key = (key, backend, schedule)
     with self._lock:
       cell = self._cells.get(cell_key)
       if cell is None:
         cell = self._cells[cell_key] = _Ewma(self._alpha)
-      cell.add(seconds / slots)
+      cell.add(seconds / requests)
 
   def observe_iterations(self, key, iterations) -> None:
     """Measured per-request convergence counts from one closure batch (the
-    live slots only — padded copies would double-count their template).
+    live slots only — inert padding slots converge at once and would drag
+    the mean down).
     Recorded separately from batch seconds so a batch that fails *after*
     the fixpoint ran (the poisoned-batch path) still contributes what it
     measured."""
@@ -159,11 +161,11 @@ class ServiceEstimator:
     trips ('static' — byte-for-byte the non-adaptive prediction).
 
     Observations are keyed by the schedule that *actually executed*, and
-    per-batch placement may downgrade a distributed bucket to 'local'
-    (e.g. dp batches whose size does not divide the mesh), so when the
-    distributed cell is still cold the bucket's local cell answers before
-    the static prior does — measured local latency beats an idealized
-    model, and the two regimes' readings are never averaged together."""
+    a breaker may re-dispatch a distributed bucket to its 'local' arm, so
+    when the distributed cell is still cold the bucket's local cell
+    answers before the static prior does — measured local latency beats
+    an idealized model, and the two regimes' readings are never averaged
+    together."""
     with self._lock:
       cell = self._cells.get((key, backend, schedule))
       warm = cell is not None and cell.count >= self.min_observations

@@ -29,7 +29,9 @@ The export format is Chrome trace-event JSON (``export()`` →
     batch, H2D bytes, measured iterations), ``split_results``.  Together
     these are the host/device time breakdown per batch.  Inside them:
     ``batch_dispatch`` (the compiled call returning, operand staging
-    included) and ``batch_wait`` (``block_until_ready``) partition
+    included; on a mesh-placed batch its args carry the schedule, padded
+    size, live slots and devices holding one) and ``batch_wait``
+    (``block_until_ready``) partition
     ``device_compute``; ``batch_d2h`` (the result's copy to the host)
     opens ``split_results``;
   * per-tick arena phases — ``arena_tick`` (launch + sweep) partitioned by
@@ -320,7 +322,8 @@ class FlightRecorder:
                      arrivals_s: Sequence[float],
                      iterations=None, emit_pick: bool = True,
                      dispatched_s: Optional[float] = None,
-                     fetched_s: Optional[float] = None) -> None:
+                     fetched_s: Optional[float] = None,
+                     sharding: Optional[dict] = None) -> None:
     """Emit one completed batch's whole event set in a single lock
     acquisition: the four phase spans (pad_and_stack / resolve_compile /
     device_compute / split_results), their children when stamped —
@@ -336,7 +339,11 @@ class FlightRecorder:
     retried/bisected sub-batches already closed ``queued`` and opened a
     fresh ``execute`` slice via ``batch_attempt_fail`` /
     ``batch_attempt_begin``, so only the terminal ``execute`` end is
-    emitted here — one ``e`` per ``b`` per attempt."""
+    emitted here — one ``e`` per ``b`` per attempt.
+
+    ``sharding`` (a mesh-placed batch only: its ``schedule``, padded size
+    ``rb``, ``live`` request slots and ``chips_live``, the devices holding
+    at least one of them) becomes the ``batch_dispatch`` span's args."""
     if not self.enabled:
       return
     tid = self._tid()
@@ -356,7 +363,7 @@ class FlightRecorder:
     ]
     if dispatched_s is not None:
       events.append(_span("batch_dispatch", "batch", tid, executed_s,
-                          dispatched_s))
+                          dispatched_s, sharding))
       events.append(_span("batch_wait", "batch", tid, dispatched_s,
                           device_s))
     events.append(_span("device_compute", "batch", tid, executed_s, device_s,

@@ -130,7 +130,7 @@ class SchedulingPolicy:
     the engine runs adaptive) fits the cap, so a bulk batch on device can
     delay an urgent arrival by at most ~max_batch_seconds instead of a full
     max_batch service time.  Power-of-two flooring matters: the engine pads
-    batches up to the next power of two and computes every padded slot, so
+    batches up to the next power of two and runs that padded program, so
     an un-floored cap of e.g. 3 would execute 4 slots and overshoot the
     seconds budget it claims to honor.  Never caps below 1; without a cap
     (or predictor) the answer is ``sched.max_batch`` — the historical

@@ -63,9 +63,9 @@ def test_ewma_converges_to_shifted_load_within_half_lives():
 
 
 def test_observations_normalized_per_padded_slot():
-  """A batch's seconds are divided by its padded slot count: marginal
-  per-request cost, the unit every consumer (admission backlog, deadline
-  feasibility, batch cap) is denominated in."""
+  """A batch's seconds are divided by the live requests it served (its
+  padding slots are inert): per-request cost, the unit every consumer
+  (admission backlog, deadline feasibility, batch cap) is denominated in."""
   est = ServiceEstimator(min_observations=1)
   key = _mmo_key()
   est.observe_batch(key, "xla", "local", 8, 0.8)

@@ -194,6 +194,29 @@ def test_batch_children_partition_their_parents():
       "pad_and_stack", "resolve_compile", "device_compute", "split_results"]
 
 
+def _dispatch_args(mesh):
+  """The ``batch_dispatch`` spans' args of three APSP requests served as one
+  batch, by an engine on ``mesh`` (dp) or with none."""
+  kw = {} if mesh is None else dict(mesh=mesh, schedule="dp",
+                                    shard_flops=0.0)
+  eng = MMOEngine(backend="xla", max_batch=4, **kw)
+  for i in range(3):
+    eng.submit(_apsp_req(10, seed=i))
+  eng.run_until_idle()
+  return [ev.get("args") for ev in eng.tracer.events()
+          if ev["name"] == "batch_dispatch"]
+
+
+def test_batch_dispatch_of_a_dp_batch_carries_its_placement():
+  """A mesh-placed batch's ``batch_dispatch`` span says how it was laid out:
+  schedule, padded size, live slots, devices holding one; a batch of an
+  engine without a mesh keeps the span without args."""
+  from repro.core.distributed import make_mesh
+  assert _dispatch_args(make_mesh((1, 1))) == [
+      {"schedule": "dp", "rb": 4, "live": 3, "chips_live": 1}]
+  assert _dispatch_args(None) == [None]
+
+
 def test_children_share_their_parents_edges_to_the_last_bit():
   """ts + dur of a child equals its parent's wherever they share an end,
   so a gap inside both overlaps them equally and the first of the two in

@@ -138,8 +138,9 @@ def test_router_threshold_and_pinned_schedule():
   # dp (independent per-device fixpoints) is allowed for closures
   eng4 = MMOEngine(backend="xla", mesh=mesh, schedule="dp", shard_flops=0.0)
   assert eng4.resolve_schedule(key) == "dp"
-  # ... and the placement never falls back on a 1-device mesh (rb % 1 == 0)
-  assert eng4.resolve_placement(key, 3)[2] == "dp"
+  # ... and every batch size runs there (a 1-device mesh divides any rb)
+  assert eng4.resolve_placement(key)[2] == "dp"
+  assert eng4._padded_batch(3, "dp") == 4
 
 
 def test_sharded_and_local_executables_never_collide():
@@ -342,7 +343,7 @@ _SCRIPT = textwrap.dedent("""
     assert eng2.cache.misses == misses, (eng2.cache.misses, misses)
     print("PREWARM_ZERO_RETRACE_OK")
 
-    # --- 5. dp engine: full batches shard, partial batches fall back ------
+    # --- 5. dp engine: every batch shards, partial ones with inert pads --
     eng3 = MMOEngine(backend="xla", mesh=mesh, schedule="dp",
                      shard_flops=1e5, max_batch=8)
     ws = {n: graphs.weighted_digraph(n, 0.25, seed=n) for n in range(49, 57)}
@@ -353,8 +354,8 @@ _SCRIPT = textwrap.dedent("""
         ref, _ = solvers.apsp(w)
         np.testing.assert_allclose(futs3[n].result().value, np.asarray(ref),
                                    atol=1e-5)
-    # 3 requests pad to rb=4, which does not divide the 8 devices: the
-    # memoized bucket schedule stays dp but the executed placement is local
+    # 3 requests pad to rb=4, which does not divide the 8 devices: rb
+    # rounds up to 8 (5 inert slots) and the batch still runs dp
     eng4 = MMOEngine(backend="xla", mesh=mesh, schedule="dp",
                      shard_flops=1e5, max_batch=8)
     futs4 = [eng4.submit(apsp_request(
@@ -366,8 +367,12 @@ _SCRIPT = textwrap.dedent("""
                                    atol=1e-5)
     (key4,) = eng4._schedules
     assert eng4._schedules[key4] == "dp"
-    assert eng4.resolve_placement(key4, 4)[2] == "local"
-    assert eng4.resolve_placement(key4, 8)[2] == "dp"
+    assert eng4.resolve_placement(key4)[2] == "dp"
+    assert [eng4._padded_batch(r, "dp") for r in (1, 3, 4, 8)] == [8] * 4
+    assert eng4._padded_batch(3, "local") == 4
+    st4 = eng4.stats()
+    assert {s for (_, _, s) in st4.arms} == {"dp"}
+    assert (st4.dp_live_slots, st4.dp_inert_slots) == (3, 5)
     print("DP_ENGINE_OK")
 """)
 

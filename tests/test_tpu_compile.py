@@ -28,7 +28,7 @@ ops = importlib.import_module("repro.kernels.ops")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
   from jax.experimental import topologies
   from jax.experimental.compilation_cache import compilation_cache
   os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -41,9 +41,20 @@ def one_chip():
   except Exception as e:  # noqa: BLE001 — no TPU compiler on this host
     jax.config.update("jax_enable_compilation_cache", was_on)
     pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-  yield SingleDeviceSharding(topo.devices[0])
+  yield topo
   jax.config.update("jax_enable_compilation_cache", was_on)
   compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+  return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh22(topo):
+  from repro.core.distributed import make_mesh
+  return make_mesh((2, 2), devices=topo.devices)
 
 
 def _spec(shape, dtype, sharding):
@@ -101,3 +112,26 @@ def test_arena_programs_compile(one_chip, op):
   for make_fn, abstract in arena._program_specs.values():
     jax.jit(make_fn()).lower(
         *(_spec(a.shape, a.dtype, one_chip) for a in abstract)).compile()
+
+
+@pytest.mark.parametrize("rb", (4, 8))
+@pytest.mark.parametrize("op", ("orand", "minmax"))
+def test_dp_closure_batch_compiles_on_the_mesh(mesh22, op, rb):
+  """The dp engine's closure batch at the smallest and largest bucket the
+  four-chip graph-query cell serves: one fixpoint per chip of the 2x2
+  mesh, the VPU kernel inside each shard, and no collective."""
+  from jax.sharding import NamedSharding, PartitionSpec as P
+
+  from repro.serve_mmo import batching
+  dtype = "bool" if op == "orand" else "float32"
+  whole = NamedSharding(mesh22, P())
+  for n in (64, mk.MAX_N):
+    key = BucketKey("closure", op, (n,), (dtype,), ("leyzorek",))
+    fn = batching.make_batch_fn(key, backend="pallas", interpret=False,
+                                mesh=mesh22, schedule="dp")
+    text = jax.jit(fn).lower(_spec((rb, n, n), dtype, whole),
+                             _spec((rb,), jnp.int32, whole)).compile(
+                             ).as_text()
+    assert "tpu_custom_call" in text
+    assert not any(c in text for c in ("all-reduce", "all-gather",
+                                       "collective-permute", "all-to-all"))
