@@ -19,16 +19,25 @@ Three pieces per bucket:
                        live size of 0 and operands that are already the
                        answer (a closure slot is its own fixpoint), so a
                        dp shard holding only those leaves its fixpoint at
-                       the first convergence check.
+                       the first convergence check.  Each operand is
+                       allocated once and each slot written once, pads in
+                       place; a closure batch of one request that already
+                       has its bucket's shape and dtype, with no inert
+                       slot, is staged with no host write at all
+                       (``zero_copy``): its operand is a view of the
+                       request's own buffer.
   ``make_batch_fn``  — the pure jax function the executable cache compiles:
                        mmo_batched / batched_*_closure (per-request
                        convergence masks) / addnorm+top-k.
   ``split_results``  — slice the padded batch output back to each request's
                        true shape.
+
+Contract: a staged batch may alias the caller's arrays, so the batch program
+never donates or writes its inputs, and nothing downstream of staging writes
+a request's arrays (``poison_output`` works on a copy).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Sequence
 
 import jax
@@ -41,28 +50,18 @@ from repro.core.mmo import mmo_batched
 from repro.serve_mmo.api import MMOResult, ProblemRequest
 from repro.serve_mmo.scheduler import BucketKey
 
-def _pad2d(x: np.ndarray, rows: int, cols: int,
-           row_val, col_val) -> np.ndarray:
-  """Pad a 2-D array to (rows, cols); new rows get row_val, new cols col_val."""
-  out = np.full((rows, cols), col_val, dtype=x.dtype)
-  out[x.shape[0]:, :] = row_val
-  out[:x.shape[0], :x.shape[1]] = x
-  return out
 
-
-@functools.lru_cache(maxsize=64)
-def _filled(shape: tuple, value, dtype: str) -> np.ndarray:
-  """A read-only ``shape`` array of ``value``: one inert slot's operand."""
-  out = np.full(shape, value, np.dtype(dtype))
-  out.flags.writeable = False
-  return out
-
-
-@functools.lru_cache(maxsize=64)
-def _empty_graph(op: str, nb: int, dtype: str) -> np.ndarray:
-  """``nb`` isolated vertices: the closure slot that is its own fixpoint."""
-  out = cl_mod.pad_adjacency(np.zeros((0, 0), np.dtype(dtype)), nb, op=op)
-  out.flags.writeable = False
+def _stack_padded(xs: Sequence[np.ndarray], shape: tuple, dtype: str, pad,
+                  inert: int) -> np.ndarray:
+  """``xs`` padded with ``pad`` to ``shape`` and stacked, then ``inert``
+  slots of ``pad``: one allocation, each slot written once."""
+  out = np.empty((len(xs) + inert,) + tuple(shape), np.dtype(dtype))
+  for slot, x in zip(out, xs):
+    r, c = x.shape
+    slot[:r, :c] = x
+    slot[:r, c:] = pad
+    slot[r:] = pad
+  out[len(xs):] = pad
   return out
 
 
@@ -73,29 +72,46 @@ def _stack_mmo(key: BucketKey, reqs: Sequence[ProblemRequest], inert: int):
   if boolean:
     pa = pb = False
   (has_c,) = key.params
-  a = np.stack([_pad2d(r.arrays["a"], mb, kb, pa, pa) for r in reqs]
-               + [_filled((mb, kb), pa, key.dtypes[0])] * inert)
-  b = np.stack([_pad2d(r.arrays["b"], kb, nb, pb, pb) for r in reqs]
-               + [_filled((kb, nb), pb, key.dtypes[1])] * inert)
+  a = _stack_padded([r.arrays["a"] for r in reqs], (mb, kb), key.dtypes[0],
+                    pa, inert)
+  b = _stack_padded([r.arrays["b"] for r in reqs], (kb, nb), key.dtypes[1],
+                    pb, inert)
   # per-request live-K: lanes beyond a request's true K are contraction pads
   # (⊗(pa, pb) == ⊕-identity), so backends may skip them (ragged masked-K)
   kv = np.asarray([r.shape[1] for r in reqs] + [0] * inert, np.int32)
   if not has_c:
     return (a, b, kv)
   ident = False if boolean else sr_mod.get(key.op).oplus_identity
-  c = np.stack([_pad2d(r.arrays["c"], mb, nb, ident, ident) for r in reqs]
-               + [_filled((mb, nb), ident, key.dtypes[2])] * inert)
+  c = _stack_padded([r.arrays["c"] for r in reqs], (mb, nb), key.dtypes[2],
+                    ident, inert)
   return (a, b, c, kv)
+
+
+def zero_copy(key: BucketKey, reqs: Sequence[ProblemRequest],
+              inert: int) -> bool:
+  """Whether ``stack_batch`` stages this batch with no host write: one
+  closure request, no inert slot, its matrix already of the bucket's shape
+  and dtype, so the stacked operand is a view of the request's buffer."""
+  if key.kind != "closure" or inert or len(reqs) != 1:
+    return False
+  adj = reqs[0].arrays["adj"]
+  (nb,) = key.shape
+  return adj.shape == (nb, nb) and adj.dtype == np.dtype(key.dtypes[0])
 
 
 def _stack_closure(key: BucketKey, reqs: Sequence[ProblemRequest],
                    inert: int):
   (nb,) = key.shape
-  adj = np.stack([cl_mod.pad_adjacency(r.arrays["adj"], nb, op=key.op)
-                  for r in reqs]
-                 + [_empty_graph(key.op, nb, key.dtypes[0])] * inert)
   # true problem sizes: rows/cols beyond valid[r] are isolated-vertex padding
   valid = np.asarray([r.shape[0] for r in reqs] + [0] * inert, np.int32)
+  if zero_copy(key, reqs, inert):
+    return (reqs[0].arrays["adj"][None], valid)
+  missing, self_value = cl_mod.closure_pad_values(key.op)
+  adj = _stack_padded([r.arrays["adj"] for r in reqs], (nb, nb),
+                      key.dtypes[0], missing, inert)
+  for slot, n in zip(adj, valid):
+    pad = np.arange(n, nb)
+    slot[pad, pad] = self_value
   return (adj, valid)
 
 
@@ -104,10 +120,10 @@ def _stack_knn(key: BucketKey, reqs: Sequence[ProblemRequest], inert: int):
   # all pads are zeros (query pad rows' outputs are sliced away; padded dims
   # contribute (0-0)²=0 for real rows); ``valid`` carries each request's true
   # corpus size so the compiled program can mask padded rows out of top-k.
-  q = np.stack([_pad2d(r.arrays["queries"], qb, db, 0.0, 0.0) for r in reqs]
-               + [_filled((qb, db), 0.0, key.dtypes[0])] * inert)
-  ref = np.stack([_pad2d(r.arrays["corpus"], rb, db, 0.0, 0.0) for r in reqs]
-                 + [_filled((rb, db), 0.0, key.dtypes[1])] * inert)
+  q = _stack_padded([r.arrays["queries"] for r in reqs], (qb, db),
+                    key.dtypes[0], 0.0, inert)
+  ref = _stack_padded([r.arrays["corpus"] for r in reqs], (rb, db),
+                      key.dtypes[1], 0.0, inert)
   valid = np.asarray([r.arrays["corpus"].shape[0] for r in reqs]
                      + [0] * inert, np.int32)
   return (q, ref, valid)

@@ -965,6 +965,7 @@ class MMOEngine:
       # dp shard holding only padding) leaves its fixpoint at the first
       # check instead of recomputing a request
       stacked = batching.stack_batch(key, reqs, inert=rb - len(reqs))
+      zero_copy = batching.zero_copy(key, reqs, rb - len(reqs))
       h2d_bytes = batching.stacked_nbytes(stacked)
       stacked_s = self._clock()
       phase = "compile"
@@ -1083,6 +1084,7 @@ class MMOEngine:
             "executed_s": executed_s, "device_s": device_s,
             "dispatched_s": dispatched[0], "fetched_s": fetched_s,
             "completed_s": completed_s, "rb": rb, "h2d_bytes": h2d_bytes,
+            "zero_copy": zero_copy,
             "cache_hit": cache_hit, "backend": backend,
             "schedule": schedule, "iters_live": iters_live}
     return results, info
@@ -1117,7 +1119,8 @@ class MMOEngine:
           device_s=info["device_s"], completed_s=completed_s,
           backend=info["backend"], schedule=info["schedule"],
           batch=len(reqs), padded=info["rb"],
-          h2d_bytes=info["h2d_bytes"], cache_hit=info["cache_hit"],
+          h2d_bytes=info["h2d_bytes"], zero_copy=info["zero_copy"],
+          cache_hit=info["cache_hit"],
           request_ids=[r.request_id for r in reqs],
           arrivals_s=[r.arrival_s for r in reqs],
           iterations=info["iters_live"], emit_pick=emit_pick,

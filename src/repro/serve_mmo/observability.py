@@ -24,9 +24,11 @@ The export format is Chrome trace-event JSON (``export()`` →
     ``execute`` slice (pick → results), with kind/op/tenant on the begin
     and the terminal outcome (done / expired / failed) on the end;
   * per-batch phases — complete events (``ph`` 'X') on the executing
-    thread's track: ``pad_and_stack``, ``resolve_compile`` (args say cache
-    hit or miss), ``device_compute`` (args carry backend, schedule, padded
-    batch, H2D bytes, measured iterations), ``split_results``.  Together
+    thread's track: ``pad_and_stack`` (args carry the bytes the host wrote
+    staging the batch and whether it was a zero-copy view),
+    ``resolve_compile`` (args say cache hit or miss), ``device_compute``
+    (args carry backend, schedule, padded batch, H2D bytes, measured
+    iterations), ``split_results``.  Together
     these are the host/device time breakdown per batch.  Inside them:
     ``batch_dispatch`` (the compiled call returning, operand staging
     included; on a mesh-placed batch its args carry the schedule, padded
@@ -323,7 +325,8 @@ class FlightRecorder:
                      iterations=None, emit_pick: bool = True,
                      dispatched_s: Optional[float] = None,
                      fetched_s: Optional[float] = None,
-                     sharding: Optional[dict] = None) -> None:
+                     sharding: Optional[dict] = None,
+                     zero_copy: bool = False) -> None:
     """Emit one completed batch's whole event set in a single lock
     acquisition: the four phase spans (pad_and_stack / resolve_compile /
     device_compute / split_results), their children when stamped —
@@ -343,7 +346,12 @@ class FlightRecorder:
 
     ``sharding`` (a mesh-placed batch only: its ``schedule``, padded size
     ``rb``, ``live`` request slots and ``chips_live``, the devices holding
-    at least one of them) becomes the ``batch_dispatch`` span's args."""
+    at least one of them) becomes the ``batch_dispatch`` span's args.
+
+    ``zero_copy`` says the batch was staged as a view of its one request's
+    buffer (``batching.zero_copy``); the ``pad_and_stack`` span's
+    ``host_bytes`` is then 0, else ``h2d_bytes``: staging writes each
+    slot once."""
     if not self.enabled:
       return
     tid = self._tid()
@@ -356,7 +364,9 @@ class FlightRecorder:
     events = [
         _span("pad_and_stack", "batch", tid, scheduled_s, stacked_s,
               {"bucket": label, "batch": batch, "padded": padded,
-               "h2d_bytes": h2d_bytes}),
+               "h2d_bytes": h2d_bytes,
+               "host_bytes": 0 if zero_copy else h2d_bytes,
+               "zero_copy": zero_copy}),
         _span("resolve_compile", "batch", tid, stacked_s, executed_s,
               {"bucket": label, "cache": "hit" if cache_hit else "miss",
                "backend": backend, "schedule": schedule}),

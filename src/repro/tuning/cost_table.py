@@ -253,7 +253,8 @@ class CostTable:
            backends: Optional[Sequence[str]] = None) -> Optional[Decision]:
     """Cheapest (backend, cfg) for one bucketed call signature, or None when
     the table holds nothing for it.  Ties break toward the earlier backend in
-    ``backends`` order (deterministic dispatch)."""
+    ``backends`` order, then the smaller signature, so the choice does not
+    depend on insertion order and survives a save → load round trip."""
     order = tuple(backends) if backends else ("xla", "vector", "pallas")
     m, k, n = bucket_shape(tuple(shape))
     prefix = f"{sr_mod.get(op).name}|{m}x{k}x{n}|{np.dtype(dtype)}|"
@@ -261,16 +262,18 @@ class CostTable:
     if cache_key in self._best_cache:  # hot path: mmo resolves per call
       return self._best_cache[cache_key]
     choice: Optional[Decision] = None
+    rank = None
     for sig, entry in self.entries.items():
       if not sig.startswith(prefix):
         continue
       backend, cfg_s = sig[len(prefix):].split("|")
       if backend not in order:
         continue
-      cand = Decision(backend, _parse_cfg(cfg_s), entry.seconds, entry.source)
-      if choice is None or (cand.seconds, order.index(cand.backend)) < (
-          choice.seconds, order.index(choice.backend)):
-        choice = cand
+      cand_rank = (entry.seconds, order.index(backend), sig)
+      if rank is None or cand_rank < rank:
+        choice = Decision(backend, _parse_cfg(cfg_s), entry.seconds,
+                          entry.source)
+        rank = cand_rank
     self._best_cache[cache_key] = choice
     return choice
 

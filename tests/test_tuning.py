@@ -85,6 +85,22 @@ def test_best_is_argmin_with_deterministic_ties():
   assert resolve("minplus", 64, 64, 64, "float32", table=t).backend == "xla"
 
 
+def test_best_tie_ignores_insertion_order(tmp_path):
+  """Two configs of one backend at equal seconds: the same choice whichever
+  was recorded first, and after a save → load round trip."""
+  point = ("addnorm", (8, 8, 8), "float32", "xla")
+  picks = []
+  for cfgs in (((), (512,)), ((512,), ())):
+    t = CostTable()
+    for cfg in cfgs:
+      t.record(*point, cfg, 1e-4)
+    path = tmp_path / "t.json"
+    t.save(str(path))
+    for table in (t, CostTable.load(str(path))):
+      picks.append(table.best("addnorm", (8, 8, 8), "float32").cfg)
+  assert len(set(picks)) == 1, picks
+
+
 def test_prior_prefers_mxu_rewrites():
   """The analytic prior knows which ops ride the MXU per backend."""
   fast = prior_seconds("mma", (256, 256, 256), "float32", "xla")
