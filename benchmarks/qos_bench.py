@@ -127,7 +127,7 @@ def adaptive_interference(*, bulk_n: int, bulk_count: int, urgent_count: int,
   # differ — so the calibration engine can be the static build.
   cal = build(adaptive=False, cap=None)
   bulk_key = request_bucket(_bulk_req(bulk_n, seed=0))
-  backend, _ = cal.resolve_backend(bulk_key)
+  backend, _ = cal.placement.resolve_backend(bulk_key)
   per_req = cal.estimator.predict(bulk_key, backend, "local", 0.0, 1.0)
   assert per_req.source == "ewma", "calibration estimator never warmed"
   cap = 1.6 * per_req.seconds
@@ -200,7 +200,7 @@ def pick_bench(bucket_counts=(16, 256, 1024), picks: int = 2000) -> list:
   holds enough entries that picks never exhaust the queue mid-measurement.
   """
   from repro.serve_mmo import ProblemRequest
-  from repro.serve_mmo.scheduler import FifoBucketScheduler
+  from repro.serve_mmo.scheduler import BucketScheduler
 
   def fill(sched, n_buckets, per_bucket):
     a = np.zeros((4, 4), np.float32)
@@ -220,14 +220,14 @@ def pick_bench(bucket_counts=(16, 256, 1024), picks: int = 2000) -> list:
   rows = []
   for n_buckets in bucket_counts:
     per_bucket = max(2, picks // n_buckets + 2)
-    sched = FifoBucketScheduler(max_batch=1)
+    sched = BucketScheduler(policy="fifo", max_batch=1)
     fill(sched, n_buckets, per_bucket)
     t0 = time.perf_counter()
     for _ in range(picks):
       sched.next_batch()
     heap_ns = (time.perf_counter() - t0) / picks * 1e9
 
-    sched = FifoBucketScheduler(max_batch=1)
+    sched = BucketScheduler(policy="fifo", max_batch=1)
     fill(sched, n_buckets, per_bucket)
     t0 = time.perf_counter()
     for _ in range(picks):

@@ -38,7 +38,8 @@ def main():
   futs = {n: eng.submit(apsp_request(w)) for n, w in {**small, **big}.items()}
   eng.run_until_idle()
 
-  placement = {k.shape[0]: s for k, s in eng._schedules.items()}
+  placement = {k.shape[0]: s
+               for k, s in eng.placement.schedules().items()}
   for n, w in sorted({**small, **big}.items()):
     res = futs[n].result()
     ref, _ = solvers.apsp(w)

@@ -49,11 +49,13 @@ class LockSpec:
 GUARDED_BY = {
     ("serve_mmo/engine.py", "MMOEngine"): LockSpec(
         locks=("_lock", "_work", "_idle"),
-        attrs=("_decisions", "_schedules", "_static_cost",
-               "_fallback_arms_memo", "_records", "_batches", "_rejected",
+        attrs=("_static_cost", "_records", "_batches", "_rejected",
                "_expired", "_next_id", "_pending", "_inflight", "_running",
                "_stopped", "scheduler", "admission",
                "_arenas", "_arena_failures")),
+    ("serve_mmo/placement.py", "Placement"): LockSpec(
+        locks=("_lock",),
+        attrs=("_decisions", "_schedules", "_fallback_arms_memo")),
     ("serve_mmo/arena.py", "RequestArena"): LockSpec(
         locks=("_lock",),
         # device state handles (_c/_adj/_kv/_act/_it) are guarded too: admit

@@ -19,11 +19,11 @@ trace-time bugs:
     / ``.tolist()`` / ``np.*`` on a traced value forces a device sync
     (or a concretization error) inside the traced region.
 
-``cache-key-coverage`` is the retrace-bug gate for serve_mmo/engine.py:
+``cache-key-coverage`` is the retrace-bug gate for serve_mmo/placement.py:
 every knob fed to ``batching.make_batch_fn`` (the function the executable
-cache compiles) must either appear in the ``_exec_key`` tuple or be one of
-the engine's declared immutable attributes (set in ``__init__`` and never
-reassigned — which the rule also verifies).  A knob that varies without
+cache compiles) must either appear in the ``exec_key`` tuple or be one of
+``Placement``'s declared immutable attributes (set in ``__init__`` and
+never reassigned — which the rule also verifies).  A knob that varies without
 being keyed means two different programs share one cache slot; a knob in
 neither set is exactly the bug class PRs 2–7 had to hand-audit.
 """
@@ -309,14 +309,14 @@ def _rule_trace_safety(ctx: Context) -> list:
 
 
 # ---------------------------------------------------------------------------
-# cache-key coverage (serve_mmo/engine.py)
+# cache-key coverage (serve_mmo/placement.py)
 # ---------------------------------------------------------------------------
 
-# engine attributes allowed to feed make_batch_fn WITHOUT being in the
+# Placement attributes allowed to feed make_batch_fn WITHOUT being in the
 # executable-cache key: immutable after __init__ (verified below).  ``mesh``
 # is covered by ``_mesh_sig`` inside the key; ``interpret`` is a
 # process-lifetime debug switch.
-_ENGINE_CONSTANT_ATTRS = ("interpret", "mesh", "_mesh_sig")
+_CONSTANT_ATTRS = ("interpret", "mesh", "_mesh_sig")
 
 
 def _names_and_self_attrs(node):
@@ -330,25 +330,32 @@ def _names_and_self_attrs(node):
   return names, attrs
 
 
+def _is_make_batch_fn_call(node) -> bool:
+  return (isinstance(node, ast.Call)
+          and isinstance(node.func, (ast.Name, ast.Attribute))
+          and (node.func.id if isinstance(node.func, ast.Name)
+               else node.func.attr) == "make_batch_fn")
+
+
 @rule("cache-key-coverage", family="trace")
 def _rule_cache_key_coverage(ctx: Context) -> list:
-  """Every make_batch_fn knob must be in _exec_key or engine-constant."""
-  mod = ctx.module("serve_mmo/engine.py")
+  """Every make_batch_fn knob must be in exec_key or a Placement constant."""
+  mod = ctx.module("serve_mmo/placement.py")
   if mod is None:
     return []
   out = []
-  engine = next((n for n in ast.walk(mod.tree)
-                 if isinstance(n, ast.ClassDef) and n.name == "MMOEngine"),
-                None)
-  if engine is None:
+  placement = next((n for n in ast.walk(mod.tree)
+                    if isinstance(n, ast.ClassDef) and n.name == "Placement"),
+                   None)
+  if placement is None:
     return out
-  exec_key = next((n for n in engine.body
+  exec_key = next((n for n in placement.body
                    if isinstance(n, ast.FunctionDef)
-                   and n.name == "_exec_key"), None)
+                   and n.name == "exec_key"), None)
   if exec_key is None:
     return [Finding(rule="cache-key-coverage", path=mod.relpath,
-                    line=engine.lineno,
-                    message="MMOEngine has no _exec_key method — the "
+                    line=placement.lineno,
+                    message="Placement has no exec_key method — the "
                             "executable cache has no keying discipline to "
                             "check")]
   key_names: set = set()
@@ -359,9 +366,9 @@ def _rule_cache_key_coverage(ctx: Context) -> list:
       key_names |= n
       key_attrs |= a
 
-  # sub-check: the declared engine constants must really be constant —
-  # assigned in __init__ only
-  for item in engine.body:
+  # sub-check: the declared constants must really be constant — assigned
+  # in __init__ only
+  for item in placement.body:
     if not isinstance(item, ast.FunctionDef) or item.name == "__init__":
       continue
     for node in ast.walk(item):
@@ -372,44 +379,52 @@ def _rule_cache_key_coverage(ctx: Context) -> list:
         targets = [node.target]
       for t in targets:
         if isinstance(t, ast.Attribute) and isinstance(t.value, ast.Name) \
-            and t.value.id == "self" and t.attr in _ENGINE_CONSTANT_ATTRS:
+            and t.value.id == "self" and t.attr in _CONSTANT_ATTRS:
           out.append(Finding(
               rule="cache-key-coverage", path=mod.relpath, line=node.lineno,
-              message=f"MMOEngine.{item.name} reassigns self.{t.attr}, "
+              message=f"Placement.{item.name} reassigns self.{t.attr}, "
                       f"which cache-key coverage declares immutable — "
-                      f"either stop reassigning it or add it to _exec_key"))
+                      f"either stop reassigning it or add it to exec_key"))
 
   # every make_batch_fn call: each arg's free names must come from the key
   # (lambda defaults like ``lambda s=schedule:`` are resolved through)
   lambda_defaults: dict = {}
-  for node in ast.walk(engine):
+  for node in ast.walk(placement):
     if isinstance(node, ast.Lambda):
       args = node.args
       pos = (*args.posonlyargs, *args.args)
       for p, d in zip(pos[len(pos) - len(args.defaults):], args.defaults):
         if isinstance(d, ast.Name):
           lambda_defaults[p.arg] = d.id
-  for node in ast.walk(engine):
-    if not (isinstance(node, ast.Call)
-            and isinstance(node.func, (ast.Name, ast.Attribute))
-            and (node.func.id if isinstance(node.func, ast.Name)
-                 else node.func.attr) == "make_batch_fn"):
+  for node in ast.walk(placement):
+    if not _is_make_batch_fn_call(node):
       continue
     for value in (*node.args, *(kw.value for kw in node.keywords)):
       names, attrs = _names_and_self_attrs(value)
       names = {lambda_defaults.get(n, n) for n in names}
       loose_names = names - key_names
-      loose_attrs = attrs - key_attrs - set(_ENGINE_CONSTANT_ATTRS)
+      loose_attrs = attrs - key_attrs - set(_CONSTANT_ATTRS)
       for n in sorted(loose_names):
         out.append(Finding(
             rule="cache-key-coverage", path=mod.relpath, line=value.lineno,
             message=f"make_batch_fn consumes `{n}`, which is not in the "
-                    f"_exec_key tuple — two programs differing in `{n}` "
+                    f"exec_key tuple — two programs differing in `{n}` "
                     f"would share one executable-cache slot"))
       for a in sorted(loose_attrs):
         out.append(Finding(
             rule="cache-key-coverage", path=mod.relpath, line=value.lineno,
             message=f"make_batch_fn consumes `self.{a}`, which is neither "
-                    f"in _exec_key nor a declared engine constant — "
+                    f"in exec_key nor a declared Placement constant — "
                     f"retrace/stale-program hazard"))
+  # the key is checked only where Placement builds it: a make_batch_fn call
+  # in another serve_mmo module compiles behind an unchecked key
+  for other in ctx.modules:
+    if other is mod or "serve_mmo/" not in other.relpath:
+      continue
+    for node in ast.walk(other.tree):
+      if _is_make_batch_fn_call(node):
+        out.append(Finding(
+            rule="cache-key-coverage", path=other.relpath, line=node.lineno,
+            message="make_batch_fn called outside Placement.program — "
+                    "compile batch programs through it, behind exec_key"))
   return out

@@ -113,7 +113,6 @@ def main(argv=None):
   ap.add_argument("--backend", default="xla",
                   choices=("auto", "xla", "vector", "pallas"))
   ap.add_argument("--max-batch", type=int, default=8)
-  ap.add_argument("--min-bucket", type=int, default=8)
   ap.add_argument("--sizes", default="12,24,48",
                   help="comma-separated problem sizes")
   ap.add_argument("--seed", type=int, default=0)
@@ -284,7 +283,7 @@ def main(argv=None):
           f"(seed={args.fault_seed})")
 
   engine = MMOEngine(backend=args.backend, max_batch=args.max_batch,
-                     min_bucket=args.min_bucket, cost_table=cost_table,
+                     cost_table=cost_table,
                      mesh=mesh, schedule=args.schedule if mesh else "auto",
                      shard_flops=args.shard_flops,
                      policy=args.policy, max_queue=args.max_queue,
@@ -415,7 +414,7 @@ def main(argv=None):
       print(f"[serve_mmo] measured closure iterations: {est['iterations']}")
   if mesh is not None:
     placement: dict = {}
-    for s in engine._schedules.values():
+    for s in engine.placement.schedules().values():
       placement[s] = placement.get(s, 0) + 1
     print(f"[serve_mmo] bucket placement (buckets per schedule): {placement}")
   if not args.no_warmup and misses_during:

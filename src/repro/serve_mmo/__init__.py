@@ -28,6 +28,8 @@ them serving workloads, not one-shot library calls.  This package turns the
                   convergence masks for closures),
   cache.py      — AOT executable cache keyed by (bucket, batch, backend) so
                   steady-state traffic never retraces,
+  placement.py  — per-bucket placement: backend, mesh schedule, padded
+                  batch size, breaker fallback arms, the one compile path,
   arena.py      — device-resident request arena: slot-based continuous
                   batching for closure fixpoints (``mode="arena"``) —
                   admit/tick/evict slot lifecycle, bit-identical to the
@@ -82,8 +84,7 @@ from repro.serve_mmo.observability import FlightRecorder
 from repro.serve_mmo.policy import (DeadlinePolicy, FairSharePolicy,
                                     FifoPolicy, SchedulingPolicy, make_policy)
 from repro.serve_mmo.resilience import CircuitBreaker, ResilienceManager
-from repro.serve_mmo.scheduler import (BucketKey, BucketScheduler,
-                                       FifoBucketScheduler)
+from repro.serve_mmo.scheduler import BucketKey, BucketScheduler
 
 __all__ = [
     "ProblemRequest",
@@ -96,7 +97,6 @@ __all__ = [
     "ExecutableCache",
     "BucketKey",
     "BucketScheduler",
-    "FifoBucketScheduler",
     "SchedulingPolicy",
     "FifoPolicy",
     "DeadlinePolicy",

@@ -31,7 +31,6 @@ from typing import Optional
 import jax
 import numpy as np
 
-from repro.kernels import closure_megakernel as _megakernel
 from repro.serve_mmo import batching
 from repro.serve_mmo.admission import AdmissionController
 from repro.serve_mmo.api import (DeadlineExceededError, MMOFuture, MMOResult,
@@ -44,11 +43,10 @@ from repro.serve_mmo.faults import (ARM_FAILURE_KINDS, BatchTimeoutError,
                                     InjectedFault, NonFiniteResultError,
                                     classify_failure)
 from repro.serve_mmo.metrics import ServeMetrics, bucket_label
-from repro.serve_mmo.observability import (DEFAULT_TRACE_CAPACITY,
-                                           FlightRecorder)
+from repro.serve_mmo.observability import FlightRecorder
+from repro.serve_mmo.placement import Placement
 from repro.serve_mmo.resilience import ResilienceManager
-from repro.serve_mmo.scheduler import (BucketScheduler, MIN_BUCKET,
-                                       bucket_dim, contract_shape,
+from repro.serve_mmo.scheduler import (BucketScheduler, contract_shape,
                                        request_bucket)
 
 # the arena's (backend, block, schedule) identity for breaker/estimator
@@ -121,21 +119,18 @@ class EngineStats:
 class MMOEngine:
   """Serving engine for semiring problem requests (see api.py).
 
-  ``backend="auto"`` resolves backend *and* block config per bucket from the
-  cost table (``cost_table=`` argument, else the process-global table — see
-  repro.tuning.dispatch) at batch-build time.  Decisions are memoized per
-  bucket and baked into the executable-cache key, so a mixed-backend steady
-  state replays one stored executable per (bucket, batch) and never retraces
-  even if the global table is later mutated.
-
-  With a ``mesh``, a second routing layer places each bucket: batches whose
-  per-request contraction exceeds ``shard_flops`` execute as a batched
-  distributed schedule (core.distributed SUMMA / kspan / ring) across the
-  mesh, smaller buckets keep the single-device path.  ``schedule="auto"``
-  picks the schedule from the cost table's mesh rows (roofline-prior fallback
-  when unmeasured); a schedule name pins it.  The (schedule, mesh) placement
-  is part of the executable-cache key, so sharded and local executables never
-  collide and sharded steady state replays stored executables too.
+  Placement (``engine.placement``, serve_mmo/placement.py) decides each
+  bucket's arm.  ``backend="auto"`` resolves backend *and* block config per
+  bucket from the cost table (``cost_table=`` argument, else the
+  process-global table — see repro.tuning.dispatch) at batch-build time.
+  With a ``mesh``, batches whose per-request contraction exceeds
+  ``shard_flops`` execute as a batched distributed schedule
+  (core.distributed dp / SUMMA / kspan / ring) across the mesh, smaller
+  buckets keep the single-device path; ``schedule="auto"`` picks the
+  schedule from the cost table's mesh rows, a schedule name pins it.
+  Decisions are memoized per bucket and baked into the executable-cache key,
+  so steady state replays one stored executable per (bucket, batch, arm) and
+  never retraces even if the global table is later mutated.
 
   QoS: ``policy`` selects the scheduling policy ('fifo' — the default and
   the historical behavior, 'deadline', 'fair', or a SchedulingPolicy
@@ -170,8 +165,7 @@ class MMOEngine:
   batch's host vs device time breakdown into the metrics registry, and
   assembles ``observability_state()`` — the snapshot the Prometheus
   renderer (serve_mmo/exposition.py) and the HTTP endpoint
-  (serve_mmo/httpd.py) serve.  Tracing is on by default; its steady-state
-  overhead is asserted < 5% in benchmarks/serve_bench.py.
+  (serve_mmo/httpd.py) serve.  Tracing is on by default.
 
   Fault tolerance (DESIGN.md §Fault tolerance): a failed batch no longer
   fails every co-batched future.  The recovery driver retries the failed
@@ -204,26 +198,22 @@ class MMOEngine:
   the next tick boundary instead of queueing behind a full bucket cycle.
   Closure buckets above the megakernel's size cap
   (``closure_megakernel.MAX_N``) stay on the batch path in either mode and
-  are counted in ``stats().over_cap``; ``resolve_backend`` never hands
-  them to the megakernel.
+  are counted in ``stats().over_cap``; placement never hands them to the
+  megakernel.
   """
 
   def __init__(self, *, backend: str = "auto", max_batch: int = 8,
-               min_bucket: int = MIN_BUCKET,
                interpret: Optional[bool] = None,
                cost_table=None, mesh=None, schedule: str = "auto",
                shard_flops: float = 1e8,
                policy="fifo", max_queue: Optional[int] = None,
                tenant_quota=None, max_backlog_s: Optional[float] = None,
-               admission: Optional[AdmissionController] = None,
-               clock=None, metrics_window: int = 512,
+               clock=None,
                adaptive: bool = False,
                estimator: Optional[ServiceEstimator] = None,
                max_batch_seconds: Optional[float] = None,
                deadline_lookback_s: Optional[float] = None,
                trace: bool = True,
-               trace_capacity: int = DEFAULT_TRACE_CAPACITY,
-               tracer: Optional[FlightRecorder] = None,
                faults=None, transient_retries: int = 1,
                retry_backoff_s: float = 0.002, bisect: bool = True,
                breaker_threshold: Optional[int] = 5,
@@ -231,47 +221,30 @@ class MMOEngine:
                watchdog_s: Optional[float] = None,
                validate_results: bool = True,
                fallback_backends=None,
-               resilience: Optional[ResilienceManager] = None,
                mode: str = "batch",
                arena_capacity: int = DEFAULT_CAPACITY,
                arena_g: int = DEFAULT_ARENA_G):
-    from repro.core import distributed as dist
-    valid_schedules = ("auto", "local") + dist.SCHEDULES
-    if schedule not in valid_schedules:
-      raise ValueError(f"unknown schedule {schedule!r}; one of "
-                       f"{valid_schedules}")
     if mode not in ("batch", "arena"):
       raise ValueError(f"unknown mode {mode!r}; one of ('batch', 'arena')")
-    if mesh is None and schedule not in ("auto", "local"):
-      raise ValueError(f"schedule {schedule!r} needs a mesh")
-    self.backend = backend
-    self.interpret = interpret
-    self.cost_table = cost_table
-    self.mesh = mesh
-    self.schedule = schedule
-    self.shard_flops = float(shard_flops)
-    self._mesh_sig = None if mesh is None else tuple(
-        (a, int(mesh.shape[a])) for a in mesh.axis_names)
     self._clock = clock if clock is not None else time.perf_counter
-    self._decisions: dict = {}  # BucketKey → (backend, block cfg)
-    self._schedules: dict = {}  # BucketKey → 'local' | distributed schedule
+    self.tracer = FlightRecorder(clock=self._clock, enabled=trace)
+    self.cache = ExecutableCache(recorder=self.tracer)
+    self.placement = Placement(
+        backend=backend, cost_table=cost_table, mesh=mesh, schedule=schedule,
+        shard_flops=shard_flops, interpret=interpret,
+        fallback_backends=fallback_backends, cache=self.cache)
     self._static_cost: dict = {}  # BucketKey → (contraction s, worst trips)
     self.adaptive = bool(adaptive)
     self.estimator = estimator if estimator is not None else ServiceEstimator()
-    self.scheduler = BucketScheduler(policy=policy, min_bucket=min_bucket,
-                                     max_batch=max_batch, clock=self._clock,
+    self.scheduler = BucketScheduler(policy=policy, max_batch=max_batch,
+                                     clock=self._clock,
                                      max_batch_seconds=max_batch_seconds,
                                      deadline_lookback_s=deadline_lookback_s)
     self.scheduler.predict_seconds = self.predict_request_seconds
-    if admission is None:
-      admission = AdmissionController(max_queue=max_queue,
-                                      tenant_quota=tenant_quota,
-                                      max_backlog_s=max_backlog_s)
-    self.admission = admission
-    self.metrics = ServeMetrics(clock=self._clock, window=metrics_window)
-    self.tracer = tracer if tracer is not None else FlightRecorder(
-        capacity=trace_capacity, clock=self._clock, enabled=trace)
-    self.cache = ExecutableCache(recorder=self.tracer)
+    self.admission = AdmissionController(max_queue=max_queue,
+                                         tenant_quota=tenant_quota,
+                                         max_backlog_s=max_backlog_s)
+    self.metrics = ServeMetrics(clock=self._clock)
     # -- fault tolerance (DESIGN.md §Fault tolerance) -----------------------
     if transient_retries < 0:
       raise ValueError(f"transient_retries must be >= 0, "
@@ -282,14 +255,9 @@ class MMOEngine:
     self.bisect = bool(bisect)
     self.watchdog_s = None if watchdog_s is None else float(watchdog_s)
     self.validate_results = bool(validate_results)
-    self.fallback_backends = (None if fallback_backends is None
-                              else tuple(fallback_backends))
-    if resilience is None:
-      resilience = ResilienceManager(threshold=breaker_threshold,
-                                     probe_after_s=breaker_probe_s,
-                                     clock=self._clock)
-    self.resilience = resilience
-    self._fallback_arms_memo: dict = {}  # BucketKey → tuple of arms
+    self.resilience = ResilienceManager(threshold=breaker_threshold,
+                                        probe_after_s=breaker_probe_s,
+                                        clock=self._clock)
     # -- continuous batching (DESIGN.md §Request arena) ---------------------
     self.mode = mode
     self.arena_capacity = int(arena_capacity)
@@ -347,10 +315,11 @@ class MMOEngine:
         # arena-mode closure buckets execute on the arena arm, so their
         # static prior prices slot-seconds there (the fused-chunk roofline —
         # see tuning/cost_table.py), not whatever the batch path would pick
-        backend = "arena" if self._arena_serves(key) else self.backend
+        backend = ("arena" if self._arena_serves(key)
+                   else self.placement.backend)
         _, _, s = _dispatch.contraction_seconds(
             key.op, m, k, n, key.dtypes[0], backend=backend,
-            table=self.cost_table)
+            table=self.placement.cost_table)
         memo = (s, self._iteration_factor(key))
         self._static_cost[key] = memo
       return memo
@@ -375,12 +344,9 @@ class MMOEngine:
       # the arena's estimator cell holds measured slot-seconds per request
       # (admit → evict), observed at eviction — exactly the residency the
       # admission controller charges for
-      backend, schedule = _ARENA_ARM[0], _ARENA_ARM[2]
-      return self.estimator.predict(key, backend, schedule, contraction_s,
-                                    trips)
-    with self._lock:
-      backend, _ = self.resolve_backend(key)
-      schedule = self.resolve_schedule(key)
+      backend, _, schedule = _ARENA_ARM
+    else:
+      backend, _, schedule = self.placement.resolve(key)
     return self.estimator.predict(key, backend, schedule, contraction_s,
                                   trips)
 
@@ -406,7 +372,7 @@ class MMOEngine:
         req.deadline_at = req.arrival_s + float(req.deadline_s)
       cost = 0.0
       if self.admission.max_backlog_s is not None:
-        key = request_bucket(req, self.scheduler.min_bucket)
+        key = request_bucket(req)
         est = self.predict_request(key)
         cost = est.seconds
         req.predicted_source = est.source
@@ -436,131 +402,6 @@ class MMOEngine:
 
   # -- execution -------------------------------------------------------------
 
-  @staticmethod
-  def _batch_bucket(r: int) -> int:
-    """Round the batch size up to a power of two: the request axis is shape-
-    bucketed exactly like the problem axes, so one bucket spawns at most
-    log2(max_batch)+1 executables instead of one per arrival count."""
-    return bucket_dim(r, 1)
-
-  def _padded_batch(self, r: int, schedule: str) -> int:
-    """The batch size a placement runs ``r`` requests at: the power-of-two
-    bucket, rounded up to a multiple of the mesh's devices under dp so the
-    request axis always divides over the mesh (1–4 requests on four chips
-    run at 4, 5–8 at 8)."""
-    rb = self._batch_bucket(r)
-    if schedule == "dp":
-      rb = -(-rb // self.mesh.size) * self.mesh.size
-    return rb
-
-  @staticmethod
-  def _megakernel_serves(key) -> bool:
-    """Whether the fused megakernel (and so the arena) can hold this bucket:
-    closures whose bucket dim is within the kernel's VMEM-resident cap."""
-    return key.kind == "closure" and key.shape[0] <= _megakernel.MAX_N
-
-  def _arena_serves(self, key) -> bool:
-    return self.mode == "arena" and self._megakernel_serves(key)
-
-  def resolve_backend(self, key) -> tuple:
-    """(backend, block cfg) for one bucket — the dispatch decision.
-
-    Memoized: the first resolution a bucket ever gets is the one it keeps
-    for this engine's lifetime (stable executable-cache keys).  The whole
-    check-resolve-memoize sequence holds the engine lock: ``prewarm`` on the
-    caller thread and ``step`` on the background loop race here, and an
-    unsynchronized dict could memoize two divergent decisions if the global
-    cost table moved between their resolutions.
-    """
-    with self._lock:
-      dec = self._decisions.get(key)
-      if dec is None:
-        if self.backend == "megakernel" and not self._megakernel_serves(key):
-          # above the cap the fused arm cannot compile; the per-iteration
-          # Pallas path runs the same kernel family one contraction a time
-          dec = ("pallas", ())
-        elif self.backend != "auto":
-          dec = (self.backend, ())
-        else:
-          from repro.tuning import dispatch as _dispatch
-          m, k, n = contract_shape(key)
-          # closure buckets own a whole fixpoint, so the fused 'megakernel'
-          # arm competes for them (and only them: a single-contraction
-          # bucket can't run it) up to its size cap.  The choice flows into
-          # _exec_key via the (backend, block) slots, so cached executables
-          # stay distinct.
-          pool = (_dispatch.closure_backends(key.shape[0])
-                  if key.kind == "closure" else None)
-          d = _dispatch.resolve(key.op, m, k, n, key.dtypes[0],
-                                table=self.cost_table, backends=pool)
-          dec = (d.backend, d.cfg)
-        self._decisions[key] = dec
-      return dec
-
-  def resolve_schedule(self, key) -> str:
-    """Mesh placement for one bucket: 'local' or a distributed schedule name.
-
-    Memoized under the engine lock like ``resolve_backend`` (stable cache
-    keys); without a mesh every bucket is 'local'.
-    """
-    with self._lock:
-      sched = self._schedules.get(key)
-      if sched is None:
-        sched = self._route(key)
-        self._schedules[key] = sched
-      return sched
-
-  def _route(self, key) -> str:
-    """The size-threshold router: buckets whose per-request contraction
-    exceeds ``shard_flops`` go to the mesh, the rest stay local.  Above the
-    threshold, a pinned ``schedule`` is used as-is (when it divides onto the
-    mesh); ``"auto"`` asks the cost table's mesh rows (roofline-prior
-    fallback) whether a distributed schedule actually beats the local path.
-    Closure buckets only consider dp (independent per-device fixpoints — the
-    straggler-decoupling schedule) and SUMMA (the one contraction schedule
-    whose iterate stays sharded in place across squarings)."""
-    if self.mesh is None or self.schedule == "local":
-      return "local"
-    m, k, n = contract_shape(key)
-    if 2.0 * m * k * n < self.shard_flops:
-      return "local"
-    from repro.core import distributed as dist
-    fits = [s for s in dist.SCHEDULES
-            if dist.schedule_fits(s, m, k, n, self.mesh)]
-    if key.kind == "closure":
-      fits = [s for s in fits if s in ("dp", "summa")]
-    if self.schedule != "auto":
-      return self.schedule if self.schedule in fits else "local"
-    if not fits:
-      return "local"
-    from repro.tuning import dispatch as _dispatch
-    mesh_dims = tuple(s for _, s in self._mesh_sig)
-    d = _dispatch.resolve(key.op, m, k, n, key.dtypes[0],
-                          table=self.cost_table, mesh_shape=mesh_dims,
-                          schedules=tuple(fits))
-    return d.backend if d.backend in fits else "local"
-
-  def resolve_placement(self, key) -> tuple:
-    """(backend, block cfg, schedule) — the full per-bucket decision.  The
-    backend doubles as each shard's local contraction path when the bucket
-    is routed to the mesh.  A dp bucket runs every batch on the mesh: its
-    batch size rounds up to a multiple of the mesh's devices
-    (``_padded_batch``) and the padding slots are inert."""
-    backend, block = self.resolve_backend(key)
-    return backend, block, self.resolve_schedule(key)
-
-  def _exec_key(self, key, rb: int, backend: str, block: tuple,
-                schedule: str) -> tuple:
-    """Executable-cache key: placement included, so a bucket's sharded and
-    local programs (or programs for two different meshes) never collide."""
-    return (key, rb, backend, block, schedule,
-            None if schedule == "local" else self._mesh_sig)
-
-  @staticmethod
-  def _exec_label(key, rb: int, backend: str, schedule: str) -> str:
-    """The executable's name in its ``compile`` span."""
-    return f"{bucket_label(key)}/b{rb}/{backend}/{schedule}"
-
   def _expire_locked(self, reqs) -> None:
     """Fail requests whose deadline passed while queued (or that the policy
     failed fast as hopeless).  Engine lock held by the caller."""
@@ -568,7 +409,7 @@ class MMOEngine:
     for r in reqs:
       self.admission.on_dequeue(r)
       self.admission.on_done(r)
-      self.metrics.on_expire(request_bucket(r, self.scheduler.min_bucket))
+      self.metrics.on_expire(request_bucket(r))
       self.tracer.request_end(r.request_id, "expired", executing=False)
       fut = self._pending.pop(r.request_id, None)
       if fut is not None:
@@ -632,14 +473,138 @@ class MMOEngine:
       if not self._pending:
         self._idle.notify_all()
 
+  # -- shared completion and launch accounting ------------------------------
+
+  def _complete(self, key, done) -> int:
+    """The once-per-request final accounting of answered requests, shared by
+    the batch and arena paths: request record, admission, metrics, the
+    future, the idle notify.  ``done`` holds (request, result, scheduled_s,
+    completed_s, batch size) tuples; the engine lock is held once for all
+    of them.  Returns how many requests completed."""
+    with self._lock:
+      for r, res, scheduled_s, completed_s, batch_size in done:
+        self._inflight.discard(r.request_id)
+        self._records.append(RequestRecord(
+            request_id=r.request_id, kind=r.kind, op=r.op, bucket=tuple(key),
+            batch_size=batch_size, arrival_s=r.arrival_s,
+            scheduled_s=scheduled_s, completed_s=completed_s))
+        self.admission.on_done(r)
+        self.metrics.on_complete(key, queue_s=scheduled_s - r.arrival_s,
+                                 service_s=completed_s - scheduled_s)
+        fut = self._pending.pop(r.request_id, None)
+        if fut is not None:
+          try:
+            fut._fulfill(res)
+          except Exception as cb:  # noqa: BLE001 — a bad future callback
+            # must not take down the serving loop or its co-batched
+            # siblings; the result IS delivered (state was set before the
+            # callback ran), so this request still counts completed
+            self.tracer.instant(
+                "future_callback_error", cat="engine",
+                args={"id": r.request_id, "error": type(cb).__name__})
+      if not self._pending:
+        self._idle.notify_all()
+    return len(done)
+
+  def _count_launch_locked(self, key, label: str, backend: str,
+                           schedule: str, *, host_s: float, device_s: float,
+                           h2d_bytes: int) -> None:
+    """One successful launch (a batch, or an arena tick): the batch count,
+    its arm's count in ``stats().arms``, and the batch metrics.  Engine
+    lock held."""
+    self._batches += 1
+    arm = (label, backend, schedule)
+    self._arms[arm] = self._arms.get(arm, 0) + 1
+    self.metrics.on_batch(key, host_s=host_s, device_s=device_s,
+                          h2d_bytes=h2d_bytes)
+
+  def _arm_ok(self, key, arm: tuple, label: str) -> None:
+    """Feed a successful launch on ``arm`` (backend, block, schedule) to its
+    breaker; a breaker that recovers leaves a ``breaker_close`` instant."""
+    if self.resilience.on_success(key, arm) == "close" and self.tracer.enabled:
+      self.tracer.instant("breaker_close", cat="resilience",
+                          args={"bucket": label, "backend": arm[0],
+                                "schedule": arm[2]})
+
+  def _arm_failed(self, key, arm: tuple, label: str, exc,
+                  phase: str) -> None:
+    """Classify a failed launch on ``arm`` and count its kind.  Only
+    arm-implicating kinds feed the breaker — a host-side stack/split
+    failure would fail identically on every backend (faults.py) — and a
+    breaker that opens leaves a ``breaker_open`` instant."""
+    kind = classify_failure(exc, phase)
+    self.metrics.on_batch_failure(kind)
+    if (kind in ARM_FAILURE_KINDS
+        and self.resilience.on_failure(key, arm) == "open"
+        and self.tracer.enabled):
+      self.tracer.instant("breaker_open", cat="resilience",
+                          args={"bucket": label, "backend": arm[0],
+                                "schedule": arm[2], "kind": kind})
+
+  def _retry_backoff(self, failures: int) -> None:
+    """Count one retry and back off before it: ``retry_backoff_s`` doubled
+    per earlier consecutive failure (``failures``), at most 8×."""
+    self.metrics.on_retry()
+    backoff = self.retry_backoff_s * (2.0 ** min(failures, 3))
+    if backoff > 0.0:
+      time.sleep(backoff)
+
+  def _launch(self, fn, label: str, backend: str, rids):
+    """Run one device launch ``fn`` (a batch program, or an arena tick and
+    its sweep) behind the ``execute`` and ``slow`` fault hooks, under the
+    engine watchdog (``watchdog_s``; None = inline, the zero-overhead
+    path).  On timeout the launch fails with ``BatchTimeoutError`` instead
+    of wedging the serving loop; the worker thread is abandoned — XLA's
+    async dispatch cannot be cancelled, so the device computation may still
+    finish later and its result is discarded (DESIGN.md §Fault tolerance on
+    why this is the least-bad option)."""
+    slow_rule = None
+    if self.faults is not None:
+      if self.faults.check("execute", label=label, backend=backend,
+                           request_ids=rids):
+        raise InjectedFault("execute", label)
+      slow_rule = self.faults.check("slow", label=label, backend=backend,
+                                    request_ids=rids)
+
+    def run():
+      if slow_rule is not None:
+        time.sleep(slow_rule.delay_s)
+      return fn()
+
+    if self.watchdog_s is None:
+      return run()
+    box: dict = {}
+    done = threading.Event()
+
+    def worker():
+      try:
+        box["out"] = run()
+      except BaseException as e:  # noqa: BLE001 — marshalled to the caller
+        box["exc"] = e
+      finally:
+        done.set()
+
+    t = threading.Thread(target=worker, name="mmo-batch-watchdog",
+                         daemon=True)
+    t.start()
+    if not done.wait(self.watchdog_s):
+      raise BatchTimeoutError(label, self.watchdog_s)
+    if "exc" in box:
+      raise box["exc"]
+    return box["out"]
+
   # -- arena mode (DESIGN.md §Request arena) ---------------------------------
+
+  def _arena_serves(self, key) -> bool:
+    return self.mode == "arena" and self.placement.megakernel_serves(key)
 
   def _arena_for_locked(self, key) -> RequestArena:
     """One arena per closure bucket, created lazily.  Engine lock held."""
     arena = self._arenas.get(key)
     if arena is None:
       arena = RequestArena(key, capacity=self.arena_capacity, g=self.arena_g,
-                           cache=self.cache, interpret=self.interpret,
+                           cache=self.cache,
+                           interpret=self.placement.interpret,
                            clock=self._clock)
       self._arenas[key] = arena
       self._arena_failures[key] = 0
@@ -704,53 +669,37 @@ class MMOEngine:
     return completed
 
   def _tick_arena(self, key, arena) -> int:
-    """One tick of one arena: fault hooks, the fused chunk launch, the
-    eviction sweep, and the attempt-scoped accounting (metrics, breaker,
-    tracer) the batch path's ``_attempt`` does per launch."""
+    """One tick of one arena: the fused chunk launch and the eviction sweep,
+    with the launch accounting and completion the batch path shares."""
     label = bucket_label(key)
     rids = [r.request_id for r in arena.live_requests()]
     if not rids:
       return 0
     t0 = self._clock()
     launched = []  # the clock when the chunk launch returned
+
+    def run():
+      arena.tick()
+      launched.append(self._clock())
+      return arena.sweep()  # blocks on the tick's device flags
+
     try:
-      slow_rule = None
-      if self.faults is not None:
-        if self.faults.check("execute", label=label, backend="arena",
-                             request_ids=rids):
-          raise InjectedFault("execute", label)
-        slow_rule = self.faults.check("slow", label=label, backend="arena",
-                                      request_ids=rids)
-
-      def run():
-        if slow_rule is not None:
-          time.sleep(slow_rule.delay_s)
-        arena.tick()
-        launched.append(self._clock())
-        return arena.sweep()  # blocks on the tick's device flags
-
-      evictions = self._call_with_watchdog(run, label)
+      evictions = self._launch(run, label, _ARENA_ARM[0], rids)
     except Exception as e:  # noqa: BLE001 — classified + retried below
       self._arena_tick_failed(key, arena, e)
       return 0
     t1 = self._clock()
-    transition = self.resilience.on_success(key, _ARENA_ARM)
-    if self.tracer.enabled and transition == "close":
-      self.tracer.instant("breaker_close", cat="resilience",
-                          args={"bucket": label, "backend": "arena",
-                                "schedule": "local"})
+    self._arm_ok(key, _ARENA_ARM, label)
     with self._lock:
       self._arena_failures[key] = 0
-      self._batches += 1
-      arm = (label, _ARENA_ARM[0], _ARENA_ARM[2])
-      self._arms[arm] = self._arms.get(arm, 0) + 1
-      self.metrics.on_batch(key, host_s=0.0, device_s=t1 - t0, h2d_bytes=0)
+      self._count_launch_locked(key, label, _ARENA_ARM[0], _ARENA_ARM[2],
+                                host_s=0.0, device_s=t1 - t0, h2d_bytes=0)
     finish = None
     done = []
     completed = 0
     if evictions:
       f0 = self._clock()
-      completed = self._finish_evictions(key, arena, evictions, label, done)
+      completed = self._finish_evictions(key, evictions, label, done)
       finish = (f0, self._clock())
     if self.tracer.enabled:
       # one emission per tick: its phases, the answered evictions and the
@@ -769,22 +718,12 @@ class MMOEngine:
     (a NaN slot fails alone at eviction), so a tick-level failure is by
     construction arm-wide, not request-specific."""
     label = bucket_label(key)
-    kind = classify_failure(exc, "execute")
-    self.metrics.on_batch_failure(kind)
-    if kind in ARM_FAILURE_KINDS:
-      transition = self.resilience.on_failure(key, _ARENA_ARM)
-      if self.tracer.enabled and transition == "open":
-        self.tracer.instant("breaker_open", cat="resilience",
-                            args={"bucket": label, "backend": "arena",
-                                  "schedule": "local", "kind": kind})
+    self._arm_failed(key, _ARENA_ARM, label, exc, "execute")
     with self._lock:
-      self._arena_failures[key] = self._arena_failures.get(key, 0) + 1
-      failures = self._arena_failures[key]
+      failures = self._arena_failures.get(key, 0) + 1
+      self._arena_failures[key] = failures
     if failures <= self.transient_retries:
-      self.metrics.on_retry()
-      backoff = self.retry_backoff_s * (2.0 ** min(failures - 1, 3))
-      if backoff > 0.0:
-        time.sleep(backoff)
+      self._retry_backoff(failures - 1)
       return
     with self._lock:
       self._arena_failures[key] = 0
@@ -797,15 +736,15 @@ class MMOEngine:
                                 "error": type(exc).__name__})
     self._fail_requests(key, victims, exc)
 
-  def _finish_evictions(self, key, arena, evictions, label, done) -> int:
-    """Turn evictions into results: per-request validation, final
-    accounting, and estimator feedback.  The estimator observes measured
-    slot-seconds (admit → evict, rb=1) — the per-request residency QoS
-    predictions price — plus the measured iteration count, mirroring the
-    batch path's two feedback signals.  Appends (request id, slot,
-    iterations, completion time) of each answered request to ``done``, for
-    the tick's trace emission."""
-    completed = 0
+  def _finish_evictions(self, key, evictions, label, done) -> int:
+    """Turn evictions into results: per-request validation, estimator
+    feedback, and completion.  The estimator observes measured slot-seconds
+    (admit → evict, rb=1) — the per-request residency QoS predictions
+    price — plus the measured iteration count, mirroring the batch path's
+    two feedback signals.  Appends (request id, slot, iterations,
+    completion time) of each answered request to ``done``, for the tick's
+    trace emission."""
+    answered = []
     for ev in evictions:
       r = ev.request
       value = ev.value
@@ -823,17 +762,12 @@ class MMOEngine:
       if poisoned or bad:
         # garbage fails THIS slot's future alone; neighbors complete —
         # the isolation the batch path needs bisection for
-        self.metrics.on_batch_failure("nonfinite")
-        transition = self.resilience.on_failure(key, _ARENA_ARM)
+        exc = NonFiniteResultError(label, [ev.slot])
+        self._arm_failed(key, _ARENA_ARM, label, exc, "execute")
         if self.tracer.enabled:
-          if transition == "open":
-            self.tracer.instant("breaker_open", cat="resilience",
-                                args={"bucket": label, "backend": "arena",
-                                      "schedule": "local",
-                                      "kind": "nonfinite"})
           self.tracer.request_end(r.request_id, "failed", executing=True,
                                   args={"slot": ev.slot})
-        self._fail_requests(key, [r], NonFiniteResultError(label, [ev.slot]))
+        self._fail_requests(key, [r], exc)
         continue
       now = self._clock()
       res = MMOResult(value=value,
@@ -842,27 +776,10 @@ class MMOEngine:
       self.estimator.observe_batch(key, _ARENA_ARM[0], _ARENA_ARM[2], 1,
                                    now - ev.admit_s)
       done.append((r.request_id, ev.slot, int(ev.iterations), now))
-      with self._lock:
-        self._inflight.discard(r.request_id)
-        self._records.append(RequestRecord(
-            request_id=r.request_id, kind=r.kind, op=r.op, bucket=tuple(key),
-            batch_size=1, arrival_s=r.arrival_s, scheduled_s=ev.admit_s,
-            completed_s=now))
-        self.admission.on_done(r)
-        self.metrics.on_complete(key, queue_s=ev.admit_s - r.arrival_s,
-                                 service_s=now - ev.admit_s)
-        fut = self._pending.pop(r.request_id, None)
-        if fut is not None:
-          try:
-            fut._fulfill(res)
-          except Exception as cb:  # noqa: BLE001 — see _complete_sub
-            self.tracer.instant("future_callback_error", cat="engine",
-                                args={"id": r.request_id,
-                                      "error": type(cb).__name__})
-        if not self._pending:
-          self._idle.notify_all()
-      completed += 1
-    return completed
+      answered.append((r, res, ev.admit_s, now, 1))
+    return self._complete(key, answered)
+
+  # -- batch mode --------------------------------------------------------------
 
   def _serve_batch(self, key, reqs, scheduled_s: float) -> int:
     """The recovery driver: execute the picked batch, isolating failures by
@@ -878,7 +795,7 @@ class MMOEngine:
     and total attempts are bounded by (retries+1)·(2B−1).  Every sub-batch
     size is re-bucketed to its own padded size, so bisection launches hit
     existing executable-cache entries (prewarm compiles every batch size
-    ``_padded_batch`` can give).
+    ``placement.padded_batch`` can give).
 
     Accounting across attempts is once-per-request for final outcomes
     (``on_complete`` / ``on_fail`` / admission / futures), per-attempt for
@@ -910,10 +827,7 @@ class MMOEngine:
               picked_t_s=scheduled_s if attempt == 0 else None,
               args={"error": type(e).__name__})
         if will_retry:
-          self.metrics.on_retry()
-          backoff = self.retry_backoff_s * (2.0 ** min(attempt, 3))
-          if backoff > 0.0:
-            time.sleep(backoff)
+          self._retry_backoff(attempt)
           stack.append((sub, retries_left - 1, attempt + 1))
         elif will_bisect:
           mid = len(sub) // 2
@@ -947,11 +861,11 @@ class MMOEngine:
     trace exactly); retries stamp their own start."""
     label = bucket_label(key)
     rids = [r.request_id for r in reqs]
-    primary = self.resolve_placement(key)
-    arm, probe = self.resilience.pick(key, primary,
-                                      lambda: self._fallback_arms(key))
+    placement = self.placement
+    arm, probe = self.resilience.pick(key, placement.resolve(key),
+                                      lambda: placement.fallback_arms(key))
     backend, block, schedule = arm
-    rb = self._padded_batch(len(reqs), schedule)
+    rb = placement.padded_batch(len(reqs), schedule)
     if self.tracer.enabled and probe:
       self.tracer.instant("breaker_probe", cat="resilience",
                           args={"bucket": label, "backend": backend,
@@ -976,31 +890,17 @@ class MMOEngine:
         # must never poison the executable cache with a broken entry
         raise InjectedFault("compile", label)
       misses_before = self.cache.misses
-      compiled = self.cache.get_or_compile(
-          self._exec_key(key, rb, backend, block, schedule),
-          lambda: batching.make_batch_fn(key, backend=backend, block=block,
-                                         interpret=self.interpret,
-                                         mesh=self.mesh, schedule=schedule),
-          stacked, label=self._exec_label(key, rb, backend, schedule))
+      compiled = placement.program(key, rb, backend, block, schedule,
+                                   stacked)
       cache_hit = self.cache.misses == misses_before
       # estimator observations start AFTER compilation: a cache-miss batch
       # must not feed trace+compile time (orders of magnitude above steady
       # service) into the EWMA as if it were device latency
       executed_s = self._clock()
       phase = "execute"
-      exec_fault = slow_rule = None
       dispatched = []  # the clock when the compiled call returned
-      if faults is not None:
-        exec_fault = faults.check("execute", label=label, backend=backend,
-                                  request_ids=rids)
-        slow_rule = faults.check("slow", label=label, backend=backend,
-                                 request_ids=rids)
 
       def run():
-        if exec_fault is not None:
-          raise InjectedFault("execute", label)
-        if slow_rule is not None:
-          time.sleep(slow_rule.delay_s)
         out = compiled(*stacked)
         dispatched.append(self._clock())
         # block on the device result here so the device-compute window
@@ -1010,7 +910,7 @@ class MMOEngine:
         jax.block_until_ready(out)
         return out
 
-      out = self._call_with_watchdog(run, label)
+      out = self._launch(run, label, backend, rids)
       device_s = self._clock()
       # one D2H conversion for validation + split (np.asarray on numpy is
       # free downstream)
@@ -1054,23 +954,10 @@ class MMOEngine:
             f"split_results returned {len(results)} results for "
             f"{len(reqs)} requests in {label}")
     except Exception as e:  # noqa: BLE001 — classify, count, feed the breaker
-      kind = classify_failure(e, phase)
-      self.metrics.on_batch_failure(kind)
-      # only arm-implicating kinds feed the breaker: a host-side stack/split
-      # failure would fail identically on every backend (faults.py)
-      transition = (self.resilience.on_failure(key, arm)
-                    if kind in ARM_FAILURE_KINDS else None)
-      if self.tracer.enabled and transition == "open":
-        self.tracer.instant("breaker_open", cat="resilience",
-                            args={"bucket": label, "backend": backend,
-                                  "schedule": schedule, "kind": kind})
+      self._arm_failed(key, arm, label, e, phase)
       raise
     completed_s = self._clock()
-    transition = self.resilience.on_success(key, arm)
-    if self.tracer.enabled and transition == "close":
-      self.tracer.instant("breaker_close", cat="resilience",
-                          args={"bucket": label, "backend": backend,
-                                "schedule": schedule})
+    self._arm_ok(key, arm, label)
     # live service-latency feedback: the same signal that fills the metrics
     # windows (minus compile time — see executed_s above), normalized per
     # live request: inert padding slots leave their fixpoint at once (and
@@ -1089,36 +976,29 @@ class MMOEngine:
             "schedule": schedule, "iters_live": iters_live}
     return results, info
 
-  def _chips_live(self, schedule: str, live: int, rb: int) -> int:
-    """Devices holding at least one request of a sharded batch: dp gives
-    each device ``rb / P`` consecutive slots, requests first; the problem-
-    axis schedules split every request over every device."""
-    if schedule != "dp":
-      return self.mesh.size
-    return -(-live // (rb // self.mesh.size))
-
   def _complete_sub(self, key, reqs, results, info, scheduled_s: float,
                     *, emit_pick: bool) -> int:
-    """Complete one successful sub-batch attempt: trace emission, batch
-    metrics, and the once-per-request final accounting.  ``scheduled_s``
+    """Complete one successful sub-batch attempt: trace emission, launch
+    accounting, and the once-per-request final accounting.  ``scheduled_s``
     stays the ORIGINAL batch pick time — queue/service windows and request
     records measure what the caller experienced (service includes retry
     time), while the batch phase spans use the attempt's own timestamps."""
+    label = bucket_label(key)
     completed_s = info["completed_s"]
+    schedule, rb = info["schedule"], info["rb"]
     if self.tracer.enabled:
-      schedule, rb = info["schedule"], info["rb"]
       sharding = None if schedule == "local" else {
           "schedule": schedule, "rb": rb, "live": len(reqs),
-          "chips_live": self._chips_live(schedule, len(reqs), rb)}
+          "chips_live": self.placement.chips_live(schedule, len(reqs), rb)}
       # one call carries the whole attempt's event set (phase spans and
       # their children, member picks + dones) so the steady-state tracing
       # cost is one lock acquisition per batch, not per request
       self.tracer.batch_complete(
-          label=bucket_label(key), scheduled_s=info["start_s"],
+          label=label, scheduled_s=info["start_s"],
           stacked_s=info["stacked_s"], executed_s=info["executed_s"],
           device_s=info["device_s"], completed_s=completed_s,
-          backend=info["backend"], schedule=info["schedule"],
-          batch=len(reqs), padded=info["rb"],
+          backend=info["backend"], schedule=schedule,
+          batch=len(reqs), padded=rb,
           h2d_bytes=info["h2d_bytes"], zero_copy=info["zero_copy"],
           cache_hit=info["cache_hit"],
           request_ids=[r.request_id for r in reqs],
@@ -1127,120 +1007,20 @@ class MMOEngine:
           dispatched_s=info["dispatched_s"], fetched_s=info["fetched_s"],
           sharding=sharding)
     with self._lock:
-      self._batches += 1
-      arm = (bucket_label(key), info["backend"], info["schedule"])
-      self._arms[arm] = self._arms.get(arm, 0) + 1
-      if info["schedule"] == "dp":
-        self._dp_live += len(reqs)
-        self._dp_inert += info["rb"] - len(reqs)
-      if (key.kind == "closure" and not self._megakernel_serves(key)
-          and (self.mode == "arena" or self.backend == "megakernel")):
-        self._over_cap += len(reqs)
-      self.metrics.on_batch(
-          key,
+      self._count_launch_locked(
+          key, label, info["backend"], schedule,
           host_s=((info["stacked_s"] - info["start_s"])
                   + (completed_s - info["device_s"])),
           device_s=info["device_s"] - info["executed_s"],
           h2d_bytes=info["h2d_bytes"])
-      for r in reqs:
-        self._inflight.discard(r.request_id)
-      for r, res in zip(reqs, results):
-        self._records.append(RequestRecord(
-            request_id=r.request_id, kind=r.kind, op=r.op, bucket=tuple(key),
-            batch_size=len(reqs), arrival_s=r.arrival_s,
-            scheduled_s=scheduled_s, completed_s=completed_s))
-        self.admission.on_done(r)
-        self.metrics.on_complete(key, queue_s=scheduled_s - r.arrival_s,
-                                 service_s=completed_s - scheduled_s)
-        fut = self._pending.pop(r.request_id, None)
-        if fut is not None:
-          try:
-            fut._fulfill(res)
-          except Exception as cb:  # noqa: BLE001 — a bad future callback
-            # must not take down the serving loop or its co-batched
-            # siblings; the result IS delivered (state was set before the
-            # callback ran), so this request still counts completed
-            self.tracer.instant(
-                "future_callback_error", cat="engine",
-                args={"id": r.request_id, "error": type(cb).__name__})
-      if not self._pending:
-        self._idle.notify_all()
-    return len(reqs)
-
-  def _call_with_watchdog(self, fn, label: str):
-    """Run ``fn`` under the engine watchdog (``watchdog_s``; None = inline,
-    the historical zero-overhead path).  On timeout the batch fails with
-    ``BatchTimeoutError`` instead of wedging the serving loop; the worker
-    thread is abandoned — XLA's async dispatch cannot be cancelled, so the
-    device computation may still finish later and its result is discarded
-    (DESIGN.md §Fault tolerance on why this is the least-bad option)."""
-    if self.watchdog_s is None:
-      return fn()
-    box: dict = {}
-    done = threading.Event()
-
-    def worker():
-      try:
-        box["out"] = fn()
-      except BaseException as e:  # noqa: BLE001 — marshalled to the caller
-        box["exc"] = e
-      finally:
-        done.set()
-
-    t = threading.Thread(target=worker, name="mmo-batch-watchdog",
-                         daemon=True)
-    t.start()
-    if not done.wait(self.watchdog_s):
-      raise BatchTimeoutError(label, self.watchdog_s)
-    if "exc" in box:
-      raise box["exc"]
-    return box["out"]
-
-  def _fallback_arms(self, key) -> tuple:
-    """Sibling arms for breaker re-dispatch, best first: every arm computes
-    bit-identical results for this bucket (one substrate, many kernels —
-    the SIMD² property), so traffic can move between them freely.
-
-    Order: a sharded bucket's first fallback is its own backend on the
-    local path (same kernel, no mesh collectives — survives schedule-level
-    faults); then the other backends on the local path ranked by cost-table
-    seconds, with the reference dense backend ('vector' — pure jnp, works
-    everywhere) forced last as the terminal arm.  ``fallback_backends``
-    overrides the backend order outright (deterministic tests, operator
-    pinning).  Memoized per bucket: stable executable-cache keys."""
-    with self._lock:
-      memo = self._fallback_arms_memo.get(key)
-      if memo is not None:
-        return memo
-      primary_backend, block = self.resolve_backend(key)
-      schedule = self.resolve_schedule(key)
-      arms = []
-      if schedule != "local":
-        arms.append((primary_backend, block, "local"))
-      if self.fallback_backends is not None:
-        order = [b for b in self.fallback_backends if b != primary_backend]
-      else:
-        from repro.tuning import dispatch as _dispatch
-        m, k, n = contract_shape(key)
-        ranked = []
-        for b in ("xla", "pallas"):
-          if b == primary_backend:
-            continue
-          try:
-            _, _, s = _dispatch.contraction_seconds(
-                key.op, m, k, n, key.dtypes[0], backend=b,
-                table=self.cost_table)
-          except Exception:  # noqa: BLE001 — an unpriceable arm is skipped
-            continue
-          ranked.append((s, b))
-        ranked.sort()
-        order = [b for _, b in ranked]
-        if primary_backend != "vector":
-          order.append("vector")
-      arms.extend((b, (), "local") for b in order)
-      memo = tuple(arms)
-      self._fallback_arms_memo[key] = memo
-      return memo
+      if schedule == "dp":
+        self._dp_live += len(reqs)
+        self._dp_inert += rb - len(reqs)
+      if (key.kind == "closure" and not self.placement.megakernel_serves(key)
+          and (self.mode == "arena" or self.placement.backend == "megakernel")):
+        self._over_cap += len(reqs)
+      return self._complete(key, [(r, res, scheduled_s, completed_s, len(reqs))
+                                  for r, res in zip(reqs, results)])
 
   def run_until_idle(self) -> int:
     """Drain the queue synchronously; returns total requests completed."""
@@ -1356,15 +1136,12 @@ class MMOEngine:
 
   def prewarm(self, sample_reqs) -> int:
     """Compile every (bucket, padded batch) executable the sample's buckets
-    can produce, without executing anything.  Returns #programs compiled.  After
-    ``prewarm``, traffic confined to those buckets causes zero recompiles —
-    the steady-state guarantee benchmarks/serve_bench.py asserts.
-    """
-    from repro.serve_mmo.scheduler import request_bucket
+    can produce, without executing anything.  Returns #programs compiled.
+    After ``prewarm``, traffic confined to those buckets causes zero
+    recompiles."""
     with self._lock:  # scheduler config is engine-lock guarded state
-      min_bucket = self.scheduler.min_bucket
       max_batch = self.scheduler.max_batch
-    seen = {request_bucket(req, min_bucket) for req in sample_reqs}
+    seen = {request_bucket(req) for req in sample_reqs}
     before = self.cache.misses
     for key in seen:
       if self._arena_serves(key):
@@ -1375,17 +1152,12 @@ class MMOEngine:
           arena = self._arena_for_locked(key)
         arena.prewarm()
         continue
-      backend, block, schedule = self.resolve_placement(key)
-      sizes = {self._padded_batch(min(1 << i, max_batch), schedule)
+      backend, block, schedule = self.placement.resolve(key)
+      sizes = {self.placement.padded_batch(min(1 << i, max_batch), schedule)
                for i in range(max_batch.bit_length() + 1)}
       for rb in sorted(sizes):
-        self.cache.get_or_compile(
-            self._exec_key(key, rb, backend, block, schedule),
-            lambda: batching.make_batch_fn(
-                key, backend=backend, block=block, interpret=self.interpret,
-                mesh=self.mesh, schedule=schedule),
-            batching.abstract_batch(key, rb),
-            label=self._exec_label(key, rb, backend, schedule))
+        self.placement.program(key, rb, backend, block, schedule,
+                               batching.abstract_batch(key, rb))
     return self.cache.misses - before
 
   # -- background serving loop -----------------------------------------------

@@ -40,14 +40,13 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 from repro.serve_mmo.api import ProblemRequest
-from repro.serve_mmo.policy import FifoPolicy, QueueEntry, make_policy
+from repro.serve_mmo.policy import QueueEntry, make_policy
 # Canonical bucketing lives in tuning.cost_table so the cost table's key —
 # the bucket signature — is the same function of a shape everywhere.
 from repro.tuning.cost_table import MIN_BUCKET, bucket_dim, bucket_shape
 
 __all__ = ["MIN_BUCKET", "BucketKey", "bucket_dim", "bucket_shape",
-           "contract_shape", "request_bucket", "BucketScheduler",
-           "FifoBucketScheduler"]
+           "contract_shape", "request_bucket", "BucketScheduler"]
 
 
 class BucketKey(NamedTuple):
@@ -95,8 +94,7 @@ class BucketScheduler:
 
   DEADLINE_LOOKBACK_S = 1.0  # default recency window for the batch cap
 
-  def __init__(self, *, policy="fifo", min_bucket: int = MIN_BUCKET,
-               max_batch: int = 8, clock=None,
+  def __init__(self, *, policy="fifo", max_batch: int = 8, clock=None,
                max_batch_seconds: Optional[float] = None,
                deadline_lookback_s: Optional[float] = None):
     if max_batch < 1:
@@ -105,7 +103,6 @@ class BucketScheduler:
       raise ValueError(
           f"max_batch_seconds must be > 0, got {max_batch_seconds}")
     self.policy = make_policy(policy)
-    self.min_bucket = min_bucket
     self.max_batch = max_batch
     self.max_batch_seconds = max_batch_seconds
     self.deadline_lookback_s = (self.DEADLINE_LOOKBACK_S
@@ -133,7 +130,7 @@ class BucketScheduler:
     now = self._clock()
     if req.deadline_s is not None and req.deadline_at is None:
       req.deadline_at = now + float(req.deadline_s)
-    key = request_bucket(req, self.min_bucket)
+    key = request_bucket(req)
     entry = QueueEntry(self._seq, req, self.policy.request_rank(req, now))
     self._seq += 1
     if req.deadline_at is not None:
@@ -247,13 +244,3 @@ class BucketScheduler:
     expired, self._expired = self._expired, []
     return expired
 
-
-class FifoBucketScheduler(BucketScheduler):
-  """Back-compat name: the scheduler pinned to the FIFO policy (strict FIFO
-  within a bucket, oldest-head-first across buckets — the engine's
-  historical behavior, byte-for-byte)."""
-
-  def __init__(self, *, min_bucket: int = MIN_BUCKET, max_batch: int = 8,
-               clock=None):
-    super().__init__(policy=FifoPolicy(), min_bucket=min_bucket,
-                     max_batch=max_batch, clock=clock)

@@ -215,46 +215,65 @@ def test_trace_safety_propagates_through_helpers(tmp_path):
 
 
 def test_cache_key_coverage_flags_unkeyed_knob(tmp_path):
-  root = _tree(tmp_path, {"serve_mmo/engine.py": """
+  root = _tree(tmp_path, {"serve_mmo/placement.py": """
       from repro.serve_mmo import batching
 
-      class MMOEngine:
+      class Placement:
         def __init__(self):
           self.interpret = False
           self.flavor = "x"
 
-        def _exec_key(self, key, rb, backend):
+        def exec_key(self, key, rb, backend):
           return (key, rb, backend)
 
-        def go(self, key, rb, backend, block):
+        def program(self, key, rb, backend, block):
           return self.cache.get_or_compile(
-              self._exec_key(key, rb, backend),
+              self.exec_key(key, rb, backend),
               lambda: batching.make_batch_fn(
                   key, backend=backend, block=block,
                   interpret=self.interpret, mesh=self.mesh),
               ())
-  """})
-  msgs = [f.message for f in _run(root, "cache-key-coverage").findings]
-  assert any("`block`" in m for m in msgs)       # name not in key tuple
-  # mesh/interpret are declared engine constants: not flagged
-  assert not any("self.interpret" in m for m in msgs)
-  assert not any("self.mesh" in m for m in msgs)
 
-
-def test_cache_key_coverage_clean_engine_passes(tmp_path):
-  root = _tree(tmp_path, {"serve_mmo/engine.py": """
+        def reroute(self, mesh):
+          self.mesh = mesh
+  """, "serve_mmo/engine.py": """
       from repro.serve_mmo import batching
 
       class MMOEngine:
-        def _exec_key(self, key, rb, backend):
+        def prewarm(self, key):
+          return batching.make_batch_fn(key, backend="xla")
+  """})
+  found = _run(root, "cache-key-coverage").findings
+  msgs = [f.message for f in found]
+  assert any("`block`" in m for m in msgs)       # name not in key tuple
+  # mesh/interpret are declared constants: not flagged as loose knobs...
+  assert not any("self.interpret" in m for m in msgs)
+  assert not any("consumes `self.mesh`" in m for m in msgs)
+  # ...but a constant that is reassigned is
+  assert any("reassigns self.mesh" in m for m in msgs)
+  # a compile outside Placement.program bypasses the checked key
+  assert [f.path for f in found if "outside Placement" in f.message] == [
+      "serve_mmo/engine.py"]
+
+
+def test_cache_key_coverage_clean_engine_passes(tmp_path):
+  root = _tree(tmp_path, {"serve_mmo/placement.py": """
+      from repro.serve_mmo import batching
+
+      class Placement:
+        def exec_key(self, key, rb, backend):
           return (key, rb, backend, self._mesh_sig)
 
-        def go(self, key, rb, backend):
+        def program(self, key, rb, backend):
           return self.cache.get_or_compile(
-              self._exec_key(key, rb, backend),
+              self.exec_key(key, rb, backend),
               lambda: batching.make_batch_fn(key, backend=backend,
                                              interpret=self.interpret),
               ())
+  """, "serve_mmo/engine.py": """
+      class MMOEngine:
+        def prewarm(self, key, rb):
+          return self.placement.program(key, rb, "xla")
   """})
   assert _run(root, "cache-key-coverage").findings == []
 

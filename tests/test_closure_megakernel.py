@@ -200,7 +200,7 @@ def test_best_default_order_excludes_megakernel():
 
 def test_engine_routes_closure_bucket_to_megakernel():
   """End to end: a cost table that says the fused arm wins a closure bucket
-  → resolve_backend picks it for closure only → the batch executes through
+  → placement.resolve_backend picks it for closure only → the batch executes through
   the megakernel (interpret mode) and returns the exact reference APSP."""
   from repro.serve_mmo import MMOEngine, apsp_request
   from repro.tuning import CostTable
@@ -213,8 +213,8 @@ def test_engine_routes_closure_bucket_to_megakernel():
   w = _line_graph(12, seed=5)
   fut = eng.submit(apsp_request(w))
   eng.run_until_idle()
-  key = next(iter(eng._decisions))
-  assert eng._decisions[key] == ("megakernel", (4,))
+  key = next(iter(eng.placement._decisions))
+  assert eng.placement.resolve_backend(key) == ("megakernel", (4,))
   ref, ref_it = cl_mod.batched_leyzorek_closure(
       cl_mod.prepare_adjacency(jnp.asarray(w), op="minplus")[None],
       op="minplus", backend="xla")
@@ -238,7 +238,7 @@ def test_engine_mmo_bucket_never_sees_megakernel():
   a = rng.standard_normal((12, 12)).astype(np.float32)
   fut = eng.submit(mmo_request(a, a, op="minplus"))
   eng.run_until_idle()
-  assert all(b != "megakernel" for b, _ in eng._decisions.values())
+  assert all(b != "megakernel" for b, _ in eng.placement._decisions.values())
   assert fut.done()
 
 
@@ -275,11 +275,11 @@ def test_engine_never_routes_megakernel_above_cap(small_cap):
   at_cap = request_bucket(apsp_request(_line_graph(12, seed=1)))
   above = request_bucket(apsp_request(_line_graph(20, seed=2)))
   auto = MMOEngine(backend="auto", cost_table=table)
-  assert auto.resolve_backend(at_cap)[0] == "megakernel"
-  assert auto.resolve_backend(above)[0] == "xla"
+  assert auto.placement.resolve_backend(at_cap)[0] == "megakernel"
+  assert auto.placement.resolve_backend(above)[0] == "xla"
   pinned = MMOEngine(backend="megakernel")
-  assert pinned.resolve_backend(at_cap)[0] == "megakernel"
-  assert pinned.resolve_backend(above)[0] == "pallas"
+  assert pinned.placement.resolve_backend(at_cap)[0] == "megakernel"
+  assert pinned.placement.resolve_backend(above)[0] == "pallas"
 
 
 def test_arena_serves_closures_above_cap_on_batch_path(small_cap):

@@ -322,8 +322,7 @@ def test_compile_span_names_the_vpu_kernel_geometry():
   spans = {ev["args"]["key"].split("/")[1]: ev["args"]
            for ev in engine.tracer.events() if ev["name"] == "compile"}
   assert set(spans) == {"minplus", "mma"}
-  m, k, n = contract_shape(request_bucket(mmo_request(a, a, op="minplus"),
-                                         engine.scheduler.min_bucket))
+  m, k, n = contract_shape(request_bucket(mmo_request(a, a, op="minplus")))
   g = sm.block_geometry("minplus", m, k, n)
   assert spans["minplus"]["kernels"] == {
       "simd2_minplus": {"block": f"{g.bm}x{g.bn}x{g.bk}",

@@ -10,8 +10,7 @@ from repro.serve_mmo import (AdmissionController, DeadlineExceededError,
                              DeadlinePolicy, FairSharePolicy, MMOEngine,
                              RejectedError, RollingWindow, apsp_request,
                              make_policy, mmo_request)
-from repro.serve_mmo.scheduler import (BucketScheduler, FifoBucketScheduler,
-                                       request_bucket)
+from repro.serve_mmo.scheduler import BucketScheduler, request_bucket
 
 from conftest import FakeClock
 
@@ -98,7 +97,7 @@ def test_already_expired_requests_diverted_under_fifo_too():
   even the FIFO scheduler refuses to batch a request whose deadline passed
   while it was queued."""
   clock = FakeClock()
-  sched = FifoBucketScheduler(max_batch=4, clock=clock)
+  sched = BucketScheduler(policy="fifo", max_batch=4, clock=clock)
   doomed = _mmo(12, deadline_s=1.0)
   ok = _mmo(12)
   sched.add(doomed)
@@ -191,7 +190,7 @@ def test_heap_pick_matches_linear_scan_reference():
   """The lazy-heap bucket picker must agree with the O(buckets) linear scan
   it replaced, across a random add/pick interleaving."""
   rng = np.random.default_rng(42)
-  sched = FifoBucketScheduler(max_batch=2)
+  sched = BucketScheduler(policy="fifo", max_batch=2)
 
   def linear_reference():
     best_key, best_seq = None, None

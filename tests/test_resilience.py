@@ -546,8 +546,8 @@ def test_fallback_chain_ends_at_reference_backend():
   for i, fut in enumerate(futs):
     ref, _ = solvers.apsp(graphs.weighted_digraph(10, 0.3, seed=i))
     np.testing.assert_allclose(fut.result().value, np.asarray(ref), atol=1e-5)
-  key = next(iter(eng._fallback_arms_memo))
-  arms = eng._fallback_arms(key)
+  key = next(iter(eng.placement._fallback_arms_memo))
+  arms = eng.placement.fallback_arms(key)
   assert arms[-1][0] == "vector"   # terminal arm is the reference backend
 
 

@@ -12,7 +12,7 @@ from repro.core import (batched_bellman_ford_closure, batched_leyzorek_closure,
                         mmo_reference, pad_adjacency, prepare_adjacency)
 from repro.serve_mmo import (MMOEngine, apsp_request, closure_request,
                              knn_request, mmo_request, reachability_request)
-from repro.serve_mmo.scheduler import (FifoBucketScheduler, bucket_dim,
+from repro.serve_mmo.scheduler import (BucketScheduler, bucket_dim,
                                        request_bucket)
 
 RNG = np.random.default_rng(0)
@@ -146,7 +146,7 @@ def test_bucketing_determinism():
 
 
 def test_scheduler_fifo_within_bucket_and_oldest_bucket_first():
-  sched = FifoBucketScheduler(max_batch=2)
+  sched = BucketScheduler(policy="fifo", max_batch=2)
   small = [apsp_request(graphs.weighted_digraph(10, 0.3, seed=i))
            for i in range(3)]
   big = apsp_request(graphs.weighted_digraph(40, 0.3, seed=7))
@@ -315,7 +315,7 @@ def test_mixed_backend_buckets_zero_retraces():
     return futs
 
   futs = traffic()
-  assert {b for b, _ in eng._decisions.values()} == {"vector", "xla"}
+  assert {b for b, _ in eng.placement._decisions.values()} == {"vector", "xla"}
   misses = eng.cache.misses
   assert misses > 0
   futs2 = traffic()  # steady state: mixed backends, zero retraces
@@ -430,7 +430,7 @@ def test_timeout_message_formats_seconds():
 
 def test_resolve_backend_is_threadsafe(monkeypatch):
   """prewarm() on the caller thread races step() on the loop thread into
-  resolve_backend; the memoization must be atomic so every caller sees one
+  placement.resolve_backend; the memoization must be atomic so every caller sees one
   decision even when the cost table's answer changes between calls."""
   import threading as th
   from repro.tuning import Decision
@@ -452,7 +452,7 @@ def test_resolve_backend_is_threadsafe(monkeypatch):
   def hammer():
     barrier.wait()
     for _ in range(10):
-      out.append(eng.resolve_backend(key))
+      out.append(eng.placement.resolve_backend(key))
 
   threads = [th.Thread(target=hammer) for _ in range(8)]
   for t in threads:
@@ -460,7 +460,7 @@ def test_resolve_backend_is_threadsafe(monkeypatch):
   for t in threads:
     t.join()
   assert len(set(out)) == 1, f"divergent memoized decisions: {set(out)}"
-  assert len(calls) == 1  # resolved exactly once, under the engine lock
+  assert len(calls) == 1  # resolved exactly once, under the placement lock
 
 
 def test_stop_drain_wakes_on_empty_pending():
@@ -573,12 +573,13 @@ def test_split_count_mismatch_fails_loudly_not_wedged(monkeypatch):
   assert eng._inflight == set() and eng.pending() == 0
 
 
-def test_future_callback_error_does_not_kill_serving():
+@pytest.mark.parametrize("mode", ["batch", "arena"])
+def test_future_callback_error_does_not_kill_serving(mode):
   """A consumer hook that raises out of future fulfillment must not take
   down the batch's siblings or the serving loop: the result is already
   delivered (state set before the hook ran), the error is traced, and the
-  request still counts completed."""
-  eng = MMOEngine(backend="xla", max_batch=4)
+  request still counts completed — for a batch and for arena evictions."""
+  eng = MMOEngine(backend="xla", max_batch=4, mode=mode)
   futs = [eng.submit(apsp_request(graphs.weighted_digraph(12, 0.3, seed=i)))
           for i in range(3)]
 

@@ -123,24 +123,24 @@ def test_router_threshold_and_pinned_schedule():
   eng = MMOEngine(backend="xla", mesh=mesh, schedule="summa",
                   shard_flops=1e12)
   key = request_bucket(apsp_request(graphs.weighted_digraph(10, 0.3, seed=0)))
-  assert eng.resolve_schedule(key) == "local"
+  assert eng.placement.resolve_schedule(key) == "local"
   # above the cutoff → the pinned schedule
   eng2 = MMOEngine(backend="xla", mesh=mesh, schedule="summa", shard_flops=0.0)
-  assert eng2.resolve_schedule(key) == "summa"
+  assert eng2.placement.resolve_schedule(key) == "summa"
   # closure buckets never route to kspan/ring (iterate must stay in place)
   eng3 = MMOEngine(backend="xla", mesh=mesh, schedule="ring", shard_flops=0.0)
-  assert eng3.resolve_schedule(key) == "local"
+  assert eng3.placement.resolve_schedule(key) == "local"
   # mmo buckets may
   mkey = request_bucket(mmo_request(np.zeros((12, 12), np.float32),
                                     np.zeros((12, 12), np.float32),
                                     op="minplus"))
-  assert eng3.resolve_schedule(mkey) == "ring"
+  assert eng3.placement.resolve_schedule(mkey) == "ring"
   # dp (independent per-device fixpoints) is allowed for closures
   eng4 = MMOEngine(backend="xla", mesh=mesh, schedule="dp", shard_flops=0.0)
-  assert eng4.resolve_schedule(key) == "dp"
+  assert eng4.placement.resolve_schedule(key) == "dp"
   # ... and every batch size runs there (a 1-device mesh divides any rb)
-  assert eng4.resolve_placement(key)[2] == "dp"
-  assert eng4._padded_batch(3, "dp") == 4
+  assert eng4.placement.resolve(key)[2] == "dp"
+  assert eng4.placement.padded_batch(3, "dp") == 4
 
 
 def test_sharded_and_local_executables_never_collide():
@@ -148,8 +148,8 @@ def test_sharded_and_local_executables_never_collide():
   eng = MMOEngine(backend="xla", mesh=_mesh11(), schedule="summa",
                   shard_flops=0.0)
   key = request_bucket(apsp_request(graphs.weighted_digraph(10, 0.3, seed=0)))
-  local_key = eng._exec_key(key, 1, "xla", (), "local")
-  shard_key = eng._exec_key(key, 1, "xla", (), "summa")
+  local_key = eng.placement.exec_key(key, 1, "xla", (), "local")
+  shard_key = eng.placement.exec_key(key, 1, "xla", (), "summa")
   assert local_key != shard_key
   assert local_key[-1] is None and shard_key[-1] == (("data", 1), ("model", 1))
 
@@ -168,7 +168,7 @@ def test_engine_sharded_path_matches_solver_on_trivial_mesh():
     return futs
 
   futs = traffic()
-  assert set(eng._schedules.values()) == {"summa"}
+  assert set(eng.placement.schedules().values()) == {"summa"}
   for fut, n in zip(futs, (9, 11, 13)):
     ref, _ = solvers.apsp(graphs.weighted_digraph(n, 0.3, seed=n))
     np.testing.assert_allclose(fut.result().value, np.asarray(ref), atol=1e-5)
@@ -320,7 +320,7 @@ _SCRIPT = textwrap.dedent("""
     futs = {n: eng.submit(apsp_request(w))
             for n, w in {**small, **big}.items()}
     eng.run_until_idle()
-    scheds = {k.shape[0]: s for k, s in eng._schedules.items()}
+    scheds = {k.shape[0]: s for k, s in eng.placement.schedules().items()}
     assert scheds == {16: "local", 64: "summa"}, scheds
     for n, w in {**small, **big}.items():
         ref, _ = solvers.apsp(w)
@@ -349,7 +349,7 @@ _SCRIPT = textwrap.dedent("""
     ws = {n: graphs.weighted_digraph(n, 0.25, seed=n) for n in range(49, 57)}
     futs3 = {n: eng3.submit(apsp_request(w)) for n, w in ws.items()}
     eng3.run_until_idle()
-    assert set(eng3._schedules.values()) == {"dp"}
+    assert set(eng3.placement.schedules().values()) == {"dp"}
     for n, w in ws.items():
         ref, _ = solvers.apsp(w)
         np.testing.assert_allclose(futs3[n].result().value, np.asarray(ref),
@@ -365,11 +365,12 @@ _SCRIPT = textwrap.dedent("""
         ref, _ = solvers.apsp(graphs.weighted_digraph(50 + i, 0.25, seed=i))
         np.testing.assert_allclose(f.result().value, np.asarray(ref),
                                    atol=1e-5)
-    (key4,) = eng4._schedules
-    assert eng4._schedules[key4] == "dp"
-    assert eng4.resolve_placement(key4)[2] == "dp"
-    assert [eng4._padded_batch(r, "dp") for r in (1, 3, 4, 8)] == [8] * 4
-    assert eng4._padded_batch(3, "local") == 4
+    (key4,) = eng4.placement.schedules()
+    assert eng4.placement.schedules()[key4] == "dp"
+    assert eng4.placement.resolve(key4)[2] == "dp"
+    assert [eng4.placement.padded_batch(r, "dp")
+            for r in (1, 3, 4, 8)] == [8] * 4
+    assert eng4.placement.padded_batch(3, "local") == 4
     st4 = eng4.stats()
     assert {s for (_, _, s) in st4.arms} == {"dp"}
     assert (st4.dp_live_slots, st4.dp_inert_slots) == (3, 5)
